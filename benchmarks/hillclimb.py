@@ -9,16 +9,14 @@ Each experiment re-lowers with a config/layout variant and reports the
 three roofline terms + peak memory.  Results land in hillclimb_results.jsonl
 and EXPERIMENTS.md §Perf.
 """
+import dataclasses
+import json
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
-
-import dataclasses   # noqa: E402
-import json          # noqa: E402
-import sys           # noqa: E402
+import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+# dryrun selects 512 emulated CPU devices when imported, before jax loads
 from repro.launch import dryrun                      # noqa: E402
 
 PEAK_FLOPS, HBM_BW, LINK_BW = 197e12, 819e9, 50e9

@@ -12,7 +12,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -28,6 +27,15 @@ _ROWS: list = []        # every CSV row, so --out covers print-only scenarios
 def _row(name, us, derived):
     print(f"{name},{us},{derived}")
     _ROWS.append({"name": name, "us_per_call": us, "derived": derived})
+
+
+def _host_env():
+    """Environment of a scenario subprocess: every scenario that times or
+    compiles runs on the CPU platform (split into host devices by its own
+    script), and this parent never imports JAX, so no process here holds an
+    accelerator another one needs."""
+    return dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+                JAX_PLATFORMS="cpu")
 
 
 # ---------------------------------------------------------------------------
@@ -141,7 +149,7 @@ print("RESULT " + json.dumps(out))
 
 
 def comm_volume():
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     proc = subprocess.run(
         [sys.executable, "-c", COMM_SCRIPT % {"src": os.path.join(ROOT, "src")}],
         env=env, capture_output=True, text=True, timeout=3000)
@@ -163,33 +171,49 @@ def comm_volume():
 
 
 # ---------------------------------------------------------------------------
-# Kernel microbenchmarks (interpret mode on CPU: correctness-grade timing)
+# Kernel microbenchmarks (interpret mode on the CPU: correctness-grade timing)
 # ---------------------------------------------------------------------------
+KERNELS_SCRIPT = r"""
+import sys, time, json
+sys.path.insert(0, %(src)r)
+import jax
+import jax.numpy as jnp
+from repro.kernels import ops
+
+def bench(fn, *args, n=5):
+    r = fn(*args)
+    jax.block_until_ready(r)
+    t0 = time.perf_counter()
+    for _ in range(n):
+        jax.block_until_ready(fn(*args))
+    return (time.perf_counter() - t0) / n * 1e6
+
+out = {}
+x = jax.random.normal(jax.random.key(0), (256, 256), jnp.float32)
+w = jax.random.normal(jax.random.key(1), (256, 256), jnp.float32)
+out["kernel_matmul_pallas_interpret|256x256x256"] = bench(
+    lambda a, b: ops.pallas_matmul(a, b), x, w)
+out["kernel_matmul_xla|256x256x256"] = bench(
+    jax.jit(lambda a, b: jnp.dot(a, b)), x, w)
+q = jax.random.normal(jax.random.key(0), (1, 256, 4, 64), jnp.float32)
+k = jax.random.normal(jax.random.key(1), (1, 256, 4, 64), jnp.float32)
+out["kernel_flash_pallas_interpret|s256h4d64"] = bench(
+    lambda a, b: ops.pallas_flash(a, b, b), q, k)
+print("RESULT " + json.dumps(out))
+"""
+
+
 def kernels():
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import ops
-
-    def bench(fn, *args, n=5):
-        r = fn(*args)
-        jax.block_until_ready(r)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            jax.block_until_ready(fn(*args))
-        return (time.perf_counter() - t0) / n * 1e6
-
-    x = jax.random.normal(jax.random.key(0), (256, 256), jnp.float32)
-    w = jax.random.normal(jax.random.key(1), (256, 256), jnp.float32)
-    us = bench(lambda a, b: ops.pallas_matmul(a, b), x, w)
-    _row("kernel_matmul_pallas_interpret|256x256x256", f"{us:.0f}", "")
-    f = jax.jit(lambda a, b: jnp.dot(a, b))
-    us = bench(f, x, w)
-    _row("kernel_matmul_xla|256x256x256", f"{us:.0f}", "")
-
-    q = jax.random.normal(jax.random.key(0), (1, 256, 4, 64), jnp.float32)
-    k = jax.random.normal(jax.random.key(1), (1, 256, 4, 64), jnp.float32)
-    us = bench(lambda a, b: ops.pallas_flash(a, b, b), q, k)
-    _row("kernel_flash_pallas_interpret|s256h4d64", f"{us:.0f}", "")
+    proc = subprocess.run(
+        [sys.executable, "-c", KERNELS_SCRIPT % {"src": os.path.join(ROOT, "src")}],
+        env=_host_env(), capture_output=True, text=True, timeout=3000)
+    for line in proc.stdout.splitlines():
+        if line.startswith("RESULT "):
+            for name, us in json.loads(line[len("RESULT "):]).items():
+                _row(name, f"{us:.0f}", "")
+            return
+    print(proc.stderr[-1500:], file=sys.stderr)
+    _row("kernels", "", "FAILED")
 
 
 # ---------------------------------------------------------------------------
@@ -229,7 +253,7 @@ print("RESULT " + json.dumps(out))
 
 
 def minirun():
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     proc = subprocess.run(
         [sys.executable, "-c", MINIRUN_SCRIPT % {"src": os.path.join(ROOT, "src")}],
         env=env, capture_output=True, text=True, timeout=3000)
@@ -305,7 +329,7 @@ print("RESULT " + json.dumps(out))
 
 
 def ppsweep():
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     proc = subprocess.run(
         [sys.executable, "-c", PPSWEEP_SCRIPT % {"src": os.path.join(ROOT, "src")}],
         env=env, capture_output=True, text=True, timeout=3000)
@@ -379,7 +403,7 @@ print("RESULT " + json.dumps(out))
 
 
 def zerosweep():
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     proc = subprocess.run(
         [sys.executable, "-c", ZEROSWEEP_SCRIPT % {"src": os.path.join(ROOT, "src")}],
         env=env, capture_output=True, text=True, timeout=3000)
@@ -572,7 +596,7 @@ print("RESULT " + json.dumps(out))
 
 
 def servesweep():
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     proc = subprocess.run(
         [sys.executable, "-c",
          SERVESWEEP_SCRIPT % {"src": os.path.join(ROOT, "src")}],
@@ -730,7 +754,7 @@ print("RESULT " + json.dumps(out))
 
 
 def overlapsweep():
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     proc = subprocess.run(
         [sys.executable, "-c",
          OVERLAPSWEEP_SCRIPT % {"src": os.path.join(ROOT, "src")}],
@@ -824,8 +848,10 @@ jax.block_until_ready(m["loss"])
 
 N = 10
 tracer = make_tracer(True)
+# the CPU has no published peak: MFU here is against the v5e figure, a
+# label for comparing these CPU runs with each other, not a device metric
 tel = TrainTelemetry(cfg, lay, global_batch=8, seq_len=128, warmup_steps=0,
-                     tracer=tracer)
+                     peak_flops_per_device=197e12, tracer=tracer)
 
 def step_baseline(p, o, i):
     p, o, m = step(p, o, batch)
@@ -877,7 +903,7 @@ print("RESULT " + json.dumps(out))
 
 def obssweep():
     import tempfile
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = _host_env()
     tmp = tempfile.mkdtemp(prefix="obssweep_")
     trace = os.path.join(tmp, "trace.json")
     proc = subprocess.run(

@@ -1,7 +1,7 @@
 """Abstract parameter trees: shape + dtype + PartitionSpec + init rule.
 
 Models declare nested dicts of ``Param``; the same tree materializes as
-  * random arrays              (init_params)          — smoke tests / training
+  * random arrays              (init_params)          — placed by the layout
   * jax.ShapeDtypeStruct       (abstract_arrays)      — dry-run lowering
   * NamedSharding trees        (shardings)            — in_shardings for jit
 """
@@ -41,10 +41,14 @@ def tree_map_params(f, tree):
     return jax.tree.map(f, tree, is_leaf=is_param)
 
 
-def init_params(tree, key, dtype=None):
-    """Materialize random arrays for a Param tree (layer-stacked dims included)."""
+def init_params(tree, key, dtype=None, layout: Optional[Layout] = None):
+    """Materialize random arrays for a Param tree (layer-stacked dims included).
+
+    One jitted program draws every leaf.  With a ``layout`` each leaf is
+    created directly in its ``NamedSharding``, so no device ever holds more
+    than its own shards; either way a leaf's f32 draw fuses into its cast
+    rather than sitting beside the cast copy."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=is_param)
-    keys = jax.random.split(key, len(leaves))
 
     def one(p: Param, k):
         dt = dtype or p.dtype
@@ -54,16 +58,20 @@ def init_params(tree, key, dtype=None):
             return jnp.ones(p.shape, dt)
         if p.init == "neg_ones":
             return jnp.full(p.shape, -1, dt)
-        if p.init == "embed":
-            return (jax.random.normal(k, p.shape, jnp.float32) * p.scale).astype(dt)
-        if p.init == "normal":
+        if p.init in ("embed", "normal"):
             return (jax.random.normal(k, p.shape, jnp.float32) * p.scale).astype(dt)
         # fan_in
         fan = p.shape[p.fan_axis] if p.shape else 1
         std = p.scale / math.sqrt(max(fan, 1))
         return (jax.random.normal(k, p.shape, jnp.float32) * std).astype(dt)
 
-    return treedef.unflatten([one(p, k) for p, k in zip(leaves, keys)])
+    def build(key):
+        keys = jax.random.split(key, len(leaves))
+        return [one(p, k) for p, k in zip(leaves, keys)]
+
+    out_shardings = (None if layout is None else
+                     [NamedSharding(layout.mesh, p.spec) for p in leaves])
+    return treedef.unflatten(jax.jit(build, out_shardings=out_shardings)(key))
 
 
 def abstract_arrays(tree, layout: Optional[Layout] = None):

@@ -58,10 +58,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 from .topology import Layout, bubble_fraction, pipeline_efficiency
 
 F32 = jnp.float32

@@ -38,11 +38,10 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .compat import auto_axis_types, make_mesh as _compat_make_mesh
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AXES = ("pod", "dp", "pp", "x", "y", "z")
+AUTO_AXES = (AxisType.Auto,) * len(AXES)
 
 
 def bubble_fraction(n_stages: int, microbatches: int) -> float:
@@ -256,8 +255,8 @@ def make_mesh(n_pod: int = 1, n_dp: int = 1, n_model: int = 1,
     if devices is not None:
         import numpy as np
         devs = np.asarray(devices).reshape(shape)
-        return Mesh(devs, AXES, **auto_axis_types(len(AXES)))
-    return _compat_make_mesh(shape, AXES)
+        return Mesh(devs, AXES, axis_types=AUTO_AXES)
+    return jax.make_mesh(shape, AXES, axis_types=AUTO_AXES)
 
 
 def make_layout(n_pod=1, n_dp=1, n_model=1, strategy="3d", cube=None,

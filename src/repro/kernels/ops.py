@@ -2,8 +2,8 @@
 
 ``enable_kernels(interpret=...)`` installs the Pallas local matmul into the
 3-D ops (ops3d.set_local_matmul) so every Algorithm-1 island computes its
-local shard product on the MXU kernel.  On CPU the kernels run in interpret
-mode; on TPU interpret=False.
+local shard product on the MXU kernel.  ``interpret=None`` is decided when a
+kernel is called: compiled on a TPU backend, interpret mode on any other.
 """
 from __future__ import annotations
 
@@ -20,13 +20,16 @@ from .paged_decode import paged_flash_decode
 from .rmsnorm import rmsnorm
 from .ssd_scan import ssd_scan
 
-ON_TPU = jax.default_backend() == "tpu"
+
+def _interpret(interpret):
+    """None -> interpret mode unless the default backend is a TPU (asked at
+    call time, so importing this module never initialises a backend)."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def pallas_matmul(x, w, *, act="none", interpret=None):
     """(…, S, K) @ (K, N): flattens the leading dims for the 2-D kernel and
     pads block sizes down for small shapes."""
-    interpret = (not ON_TPU) if interpret is None else interpret
     lead = x.shape[:-1]
     m = 1
     for s in lead:
@@ -37,34 +40,32 @@ def pallas_matmul(x, w, *, act="none", interpret=None):
     bm = 128 if m % 128 == 0 else m
     bn = 128 if n % 128 == 0 else n
     bk = 128 if k % 128 == 0 else k
-    out = matmul(x2, w, bm=bm, bn=bn, bk=bk, act=act, interpret=interpret)
+    out = matmul(x2, w, bm=bm, bn=bn, bk=bk, act=act,
+                 interpret=_interpret(interpret))
     return out.reshape(*lead, n)
 
 
 def pallas_flash(q, k, v, *, causal=True, window=0, q_offset=0, interpret=None):
-    interpret = (not ON_TPU) if interpret is None else interpret
     return flash_attention(q, k, v, causal=causal, window=window,
-                           q_offset=q_offset, interpret=interpret)
+                           q_offset=q_offset, interpret=_interpret(interpret))
 
 
 def pallas_ssd(xbar, la, Bh, Ch, *, chunk=256, interpret=None):
-    interpret = (not ON_TPU) if interpret is None else interpret
-    return ssd_scan(xbar, la, Bh, Ch, chunk=chunk, interpret=interpret)
+    return ssd_scan(xbar, la, Bh, Ch, chunk=chunk,
+                    interpret=_interpret(interpret))
 
 
 def pallas_rmsnorm(x, gamma, *, eps=1e-6, zero_centered=False, interpret=None):
-    interpret = (not ON_TPU) if interpret is None else interpret
     return rmsnorm(x, gamma, eps=eps, zero_centered=zero_centered,
-                   interpret=interpret)
+                   interpret=_interpret(interpret))
 
 
 def pallas_paged_decode(q, k_pool, v_pool, pos_pool, tables, cur, *,
                         block, window=0, scale=None, interpret=None):
     """Fused paged flash-decode through the block table (serving hot path)."""
-    interpret = (not ON_TPU) if interpret is None else interpret
     return paged_flash_decode(q, k_pool, v_pool, pos_pool, tables, cur,
                               block=block, window=window, scale=scale,
-                              impl="pallas", interpret=interpret)
+                              impl="pallas", interpret=_interpret(interpret))
 
 
 def enable_kernels(interpret=None):
@@ -72,7 +73,7 @@ def enable_kernels(interpret=None):
     island (3-D, 2-D SUMMA, 1-D Megatron) and route the serving engine's
     paged decode through the Pallas kernel."""
     from ..core import ops1d, ops2d, ops3d
-    interp = (not ON_TPU) if interpret is None else interpret
+    interp = _interpret(interpret)
 
     def local_mm(a, b):
         return pallas_matmul(a, b, interpret=interp)

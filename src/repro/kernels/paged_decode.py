@@ -47,8 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 # (impl, interpret) forced by kernels/ops.py:enable_kernels; None = auto
@@ -70,9 +68,13 @@ def _on_tpu() -> bool:
 # Pallas kernel
 # ---------------------------------------------------------------------------
 def _decode_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, kp_ref, o_ref,
-                   mo_ref, lo_ref, m_ref, l_ref, acc_ref, *, window: int,
-                   scale: float, residuals: bool):
-    j = pl.program_id(2)
+                   mo_ref, lo_ref, m_ref, l_ref, acc_ref, *, nkv: int,
+                   dk: int, dv: int, window: int, scale: float,
+                   residuals: bool):
+    # one grid step = one slot x one table block, every kv head at once:
+    # k_ref (block, nkv*dk) / v_ref (block, nkv*dv) hold the heads side by
+    # side in the lane dim, q_ref and the accumulators are (nkv, g, .)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -80,28 +82,28 @@ def _decode_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, kp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    b = pl.program_id(0)
-    cur = cur_ref[b]
-    q = q_ref[...].astype(jnp.float32) * scale          # (g, dk)
-    k = k_ref[...].astype(jnp.float32)                  # (block, dk)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, block)
+    cur = cur_ref[pl.program_id(0)]
     kp = kp_ref[0, :]                                   # (block,)
     valid = (kp >= 0) & (kp <= cur)
     if window:
         valid &= (cur - kp) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    # explicit re-mask: a fully-invalid block (the null block) would give
-    # exp(NEG_INF - NEG_INF) = 1 on the first grid step otherwise
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
-    v = v_ref[...].astype(jnp.float32)                  # (block, dv)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + p @ v
-    m_ref[...] = m_new
+    for h in range(nkv):
+        q = q_ref[h].astype(jnp.float32) * scale        # (g, dk)
+        k = k_ref[:, h * dk:(h + 1) * dk].astype(jnp.float32)   # (block, dk)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, block)
+        s = jnp.where(valid[None, :], s, NEG_INF)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit re-mask: a fully-invalid block (the null block) would
+        # give exp(NEG_INF - NEG_INF) = 1 on the first grid step otherwise
+        p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
+        v = v_ref[:, h * dv:(h + 1) * dv].astype(jnp.float32)   # (block, dv)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + p @ v
+        m_ref[h] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
         mo_ref[...] = m_ref[...]
         lo_ref[...] = l_ref[...]
@@ -123,39 +125,44 @@ def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
     nb = tables.shape[1]
     n_blocks = phys // block
     qr = q.reshape(B, nkv, g, dk)
-    kr = k_pool.reshape(n_blocks, block, nkv, dk)
-    vr = v_pool.reshape(n_blocks, block, nkv, dv)
+    # row-major views of the pool: a physical block is `block` rows of
+    # every head's features side by side, so a BlockSpec's last two dims
+    # are (block, whole row) and no head axis is squeezed out of them.
+    # On the TPU's tiled layouts XLA materializes each view as a copy.
+    kr = k_pool.reshape(n_blocks, block, nkv * dk)
+    vr = v_pool.reshape(n_blocks, block, nkv * dv)
     pr = pos_pool.reshape(n_blocks, 1, block)
 
-    kernel = functools.partial(_decode_kernel, window=window, scale=scale,
+    kernel = functools.partial(_decode_kernel, nkv=nkv, dk=dk, dv=dv,
+                               window=window, scale=scale,
                                residuals=residuals)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nkv, nb),
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((None, None, g, dk),
-                         lambda b, h, j, tbl, cp: (b, h, 0, 0)),
+            pl.BlockSpec((None, nkv, g, dk),
+                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
             # block-table indirection happens in the index map: grid step
-            # (b, h, j) pulls physical block tbl[b, j] out of the pool
-            pl.BlockSpec((None, block, None, dk),
-                         lambda b, h, j, tbl, cp: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((None, block, None, dv),
-                         lambda b, h, j, tbl, cp: (tbl[b, j], 0, h, 0)),
+            # (b, j) pulls physical block tbl[b, j] out of the pool
+            pl.BlockSpec((None, block, nkv * dk),
+                         lambda b, j, tbl, cp: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((None, block, nkv * dv),
+                         lambda b, j, tbl, cp: (tbl[b, j], 0, 0)),
             pl.BlockSpec((None, 1, block),
-                         lambda b, h, j, tbl, cp: (tbl[b, j], 0, 0)),
+                         lambda b, j, tbl, cp: (tbl[b, j], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, g, dv),
-                         lambda b, h, j, tbl, cp: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, g, 1),
-                         lambda b, h, j, tbl, cp: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, g, 1),
-                         lambda b, h, j, tbl, cp: (b, h, 0, 0)),
+            pl.BlockSpec((None, nkv, g, dv),
+                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
+            pl.BlockSpec((None, nkv, g, 1),
+                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
+            pl.BlockSpec((None, nkv, g, 1),
+                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, dv), jnp.float32),
         ],
     )
     out, m, l = pl.pallas_call(
@@ -167,8 +174,8 @@ def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
             jax.ShapeDtypeStruct((B, nkv, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, nkv, g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables.astype(jnp.int32), cur.astype(jnp.int32), qr, kr, vr, pr)
     if residuals:

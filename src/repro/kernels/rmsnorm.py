@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
 
 
 def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float, zero_centered: bool):
@@ -46,7 +45,7 @@ def rmsnorm(x, gamma, *, eps: float = 1e-6, zero_centered: bool = False,
                   pl.BlockSpec((H,), lambda i: (0,))],
         out_specs=pl.BlockSpec((bm, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, H), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, gamma)

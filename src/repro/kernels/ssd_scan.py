@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
 
 
 def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, o_ref, h_ref, *, n_chunks: int):
@@ -79,7 +78,7 @@ def ssd_scan(xbar, la, Bh, Ch, *, chunk: int = 256, interpret: bool = False):
         out_specs=pl.BlockSpec((None, chunk, dh), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, T, dh), xbar.dtype),
         scratch_shapes=[pltpu.VMEM((N, dh), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xbar, la[..., None], Bh, Ch)

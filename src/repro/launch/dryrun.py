@@ -1,8 +1,7 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
+from repro.launch.runtime import emulate_host_devices
+emulate_host_devices(512)
 # The two lines above MUST run before any jax import: the dry-run (and only
-# the dry-run) builds the production mesh from 512 placeholder host devices.
+# the dry-run) builds the production mesh from 512 placeholder CPU devices.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

@@ -53,9 +53,7 @@ _WHILE_RE2 = re.compile(r"while\(.*?\).*?body=%?([\w.\-]+),\s*"
 _CONST_RE = re.compile(r"[su]\d+\[\]\s+constant\((\d+)\)")
 _CALL_RE = re.compile(r"(?:calls=|to_apply=)%?([\w.\-]+)")
 _DOT_DIMS_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
-_DOT_LHS_RE = re.compile(
-    r" dot\((?:[a-z0-9]+\[(?P<dims>[0-9,]*)\](?:\{[^}]*\})?\s+)?"
-    r"%?(?P<name>[\w.\-]+)")
+_DOT_LHS_RE = re.compile(r" dot\(%?(?P<name>[\w.\-]+)")
 _GROUPS_RE = re.compile(r"replica_groups=\{(\{[^}]*\})")
 _GROUPS2_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 
@@ -162,22 +160,14 @@ class HloCost:
                 out_elems = _shape_elems(dims)
                 md = _DOT_DIMS_RE.search(line)
                 contract = 1
-                if md:
-                    # lhs operand: older XLA prints typed operands
-                    # ("dot(f32[32,32]{1,0} %name, ...)"), newer prints bare
-                    # names — read the inline type when present, else fall
-                    # back to the operand's def
-                    ldims = None
-                    ma = _DOT_LHS_RE.search(line)
-                    if ma:
-                        if ma.group("dims") is not None:
-                            ldims = ma.group("dims").split(",")
-                        elif ma.group("name") in self.defs:
-                            ldims = self.defs[ma.group("name")][1].split(",")
-                    if ldims:
-                        for di in md.group(1).split(","):
-                            if di:
-                                contract *= int(ldims[int(di)])
+                ma = _DOT_LHS_RE.search(line)
+                if md and ma and ma.group("name") in self.defs:
+                    # the lhs operand is a bare name: its dims come from
+                    # the operand's def
+                    ldims = self.defs[ma.group("name")][1].split(",")
+                    for di in md.group(1).split(","):
+                        if di:
+                            contract *= int(ldims[int(di)])
                 total += m * 2.0 * out_elems * contract
         return total
 

@@ -10,15 +10,16 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-from ..core.compat import make_mesh as compat_make_mesh
 from ..core.topology import Layout, factor_model_axis, make_layout
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_framework_layout(*, multi_pod: bool = False, strategy: str = "3d",

@@ -5,12 +5,12 @@ reports the serving metrics (TTFT / TPOT p50/p95, tok/s).  The same
 entrypoint drives a TPU slice (set --dp/--model); the plan is validated
 with mode='serve' so illegal compositions (pipeline stages at inference)
 fail before any device work.  Exits nonzero when no tokens were produced,
-so CI smoke runs can assert liveness by exit code.
+so CI smoke runs can assert liveness by exit code.  ``main`` returns the
+engine, the requests and the summary for callers that inspect them.
 """
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 
@@ -64,16 +64,18 @@ def main(argv=None):
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="prepend this many common tokens to every "
                          "synthetic request (exercises the prefix cache)")
-    ap.add_argument("--host-devices", type=int, default=0)
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="CPU emulation: run on the CPU platform split into "
+                         "N host devices (set before JAX is imported)")
     ap.add_argument("--trace", default="",
                     help="write a Chrome-trace of the run here (plus a "
                          "<path>.jsonl event log): one lane per request "
                          "(queue/prefill/decode spans) + the engine lane")
     args = ap.parse_args(argv)
 
+    from repro.launch.runtime import emulate_host_devices, enable_compile_cache
     if args.host_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}")
+        emulate_host_devices(args.host_devices)
 
     import dataclasses
     import jax
@@ -85,6 +87,7 @@ def main(argv=None):
     from repro.serve.metrics import format_summary
     from repro.checkpoint import store
 
+    enable_compile_cache()
     cfg = get(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -152,6 +155,7 @@ def main(argv=None):
         print(f"trace: wrote {args.trace} (+ {args.trace}.jsonl)")
     if stats["tokens"] <= 0:
         sys.exit("no tokens generated")
+    return eng, reqs, stats
 
 
 if __name__ == "__main__":

@@ -1,15 +1,14 @@
 """Training launcher: ``python -m repro.launch.train --arch tinyllama-1.1b
 --steps 100 --dp 2 --model 4 ...``.
 
-On this CPU container it runs reduced/real configs on host devices; on a TPU
-pod the same entrypoint runs the full mesh (the layout factory is identical).
+On a TPU host it builds the mesh from the attached chips; ``--host-devices N``
+emulates an N-device mesh on the CPU instead (the layout factory is identical).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
-import os
 import sys
 import time
 
@@ -52,7 +51,8 @@ def main(argv=None):
     ap.add_argument("--data", default="synthetic")
     ap.add_argument("--data-path", default="")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="force N host platform devices (set before jax init)")
+                    help="CPU emulation: run on the CPU platform split into "
+                         "N host devices (set before JAX is imported)")
     ap.add_argument("--trace", default="",
                     help="write a Chrome-trace of the run here (plus a "
                          "<path>.jsonl event log; docs/observability.md)")
@@ -62,12 +62,13 @@ def main(argv=None):
                          "the summary JSON here.  NOTE: syncs every step")
     ap.add_argument("--peak-flops", type=float, default=0,
                     help="per-device peak FLOP/s for the MFU denominator "
-                         "(default: the nominal TPU v5e constant)")
+                         "(default: the published peak of the device kind; "
+                         "required on a kind without one, e.g. the CPU)")
     args = ap.parse_args(argv)
 
+    from repro.launch.runtime import emulate_host_devices, enable_compile_cache
     if args.host_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}")
+        emulate_host_devices(args.host_devices)
 
     import jax
     import jax.numpy as jnp
@@ -81,8 +82,9 @@ def main(argv=None):
     from repro.train.step import make_train_step
     from repro.checkpoint import store
     from repro.obs import make_tracer
-    from repro.obs.telemetry import DEFAULT_PEAK_FLOPS, TrainTelemetry
+    from repro.obs.telemetry import TrainTelemetry
 
+    enable_compile_cache()
     tracer = make_tracer(bool(args.trace))
 
     cfg = get(args.arch)
@@ -110,6 +112,13 @@ def main(argv=None):
     opt_cfg = OptimConfig(name=args.optimizer, lr=args.lr, warmup=args.warmup,
                           total_steps=args.steps)
 
+    tel = None
+    if args.telemetry:
+        tel = TrainTelemetry(
+            cfg, layout, global_batch=args.batch, seq_len=args.seq,
+            peak_flops_per_device=args.peak_flops or None,
+            tracer=tracer)
+
     print(f"arch={cfg.arch} layers={cfg.n_layers} d={cfg.d_model} "
           f"mesh={dict(layout.mesh.shape)} plan={plan.describe()}")
     params = transformer.init(cfg, layout, jax.random.key(0))
@@ -120,7 +129,7 @@ def main(argv=None):
     from repro.core.params import init_params
     opt_state = init_params(
         opt_state_abstract(transformer.abstract_params(cfg, layout), layout,
-                           opt_cfg), jax.random.key(1))
+                           opt_cfg), jax.random.key(1), layout=layout)
     step_fn = jax.jit(make_train_step(cfg, layout, opt_cfg),
                       donate_argnums=(0, 1))
 
@@ -136,12 +145,6 @@ def main(argv=None):
     data = TokenStream(cfg, layout, shape,
                        DataConfig(kind=args.data, path=args.data_path))
     it = iter(data)
-    tel = None
-    if args.telemetry:
-        tel = TrainTelemetry(
-            cfg, layout, global_batch=args.batch, seq_len=args.seq,
-            peak_flops_per_device=args.peak_flops or DEFAULT_PEAK_FLOPS,
-            tracer=tracer)
     t0 = time.time()
     losses = []
     for step in range(start, args.steps):

@@ -17,7 +17,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
@@ -25,7 +25,6 @@ from ..core import ops3d
 from ..core.linear3d import (act_spec, act_spec_decode, bias_param, norm_param,
                              plinear, rmsnorm, layernorm, weight_param, wsc)
 from ..core.params import Param
-from ..core.compat import shard_map
 from ..core.topology import Dirs, Layout
 
 F32 = jnp.float32

@@ -14,13 +14,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
 from ..core.linear3d import norm_param, plinear, rmsnorm, weight_param, wsc
 from ..core.params import Param
-from ..core.compat import shard_map
 from ..core.topology import Dirs, Layout
 
 F32 = jnp.float32

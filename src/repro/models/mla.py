@@ -18,14 +18,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
 from ..core import ops3d
 from ..core.linear3d import plinear, rmsnorm, weight_param, wsc
 from ..core.params import Param
-from ..core.compat import shard_map
 from ..core.topology import Dirs, Layout
 from .blocks import _gather_axes, _head_axes, apply_rope, attention
 

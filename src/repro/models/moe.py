@@ -17,13 +17,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
 from ..core.linear3d import act_spec, act_spec_decode
 from ..core.params import Param
-from ..core.compat import shard_map
 from ..core.topology import Dirs, Layout
 
 F32 = jnp.float32

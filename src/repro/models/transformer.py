@@ -81,7 +81,7 @@ def abstract_params(cfg: ModelConfig, layout: Layout):
 
 
 def init(cfg: ModelConfig, layout: Layout, key):
-    return init_params(abstract_params(cfg, layout), key)
+    return init_params(abstract_params(cfg, layout), key, layout=layout)
 
 
 def param_counts(cfg: ModelConfig):

@@ -18,7 +18,7 @@ analytically.  This module closes the loop:
 ordering criterion ``3d < 2d < 1d`` on the *measured* per-device bytes —
 the first empirical check of the paper's cost tables on this codebase.
 
-CLI (sets XLA_FLAGS before importing jax)::
+CLI (``--host-devices`` selects the CPU before importing jax)::
 
     PYTHONPATH=src python -m repro.obs.commcheck --host-devices 8 \
         --out commcheck.json
@@ -44,7 +44,6 @@ from __future__ import annotations
 import argparse
 import json
 import math
-import os
 import sys
 from typing import Dict, List, Optional, Tuple
 
@@ -219,12 +218,13 @@ def main(argv=None):
     ap.add_argument("--out", default="",
                     help="also write the report as JSON here")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="force N host platform devices (set before jax "
-                         "init; the default plans need 8)")
+                    help="CPU emulation: run on the CPU platform split "
+                         "into N host devices (set before JAX is imported; "
+                         "the default plans need 8)")
     args = ap.parse_args(argv)
     if args.host_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}")
+        from repro.launch.runtime import emulate_host_devices
+        emulate_host_devices(args.host_devices)
     rep = check(args.arch, args.batch, args.seq, args.layers,
                 d_ff=args.d_ff, vocab=args.vocab)
     print(format_report(rep))

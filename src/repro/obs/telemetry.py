@@ -21,9 +21,9 @@ What it accounts:
   * tokens/s and model-FLOPs-utilization: the numerator comes from the
     registry's per-family ``step_flops`` hook
     (``registry.train_flops_per_token``), the denominator from
-    ``peak_flops_per_device * n_devices``.  On the CPU host-device
-    container the peak is nominal — MFU is meaningful relative across
-    plans, not absolute.
+    ``peak_flops_per_device * n_devices``.  The peak is the caller's, or
+    the ``PEAK_FLOPS`` entry for the layout's device kind; a device kind
+    missing from the table with no explicit peak is an error.
   * per-device memory watermarks: ``device.memory_stats()`` where the
     backend provides it (TPU/GPU), else a ``live_buffers`` fallback that
     sums the per-device shard bytes of every live ``jax.Array`` — the CPU
@@ -41,10 +41,19 @@ import json
 import time
 from typing import Dict, List, Optional
 
-# Nominal per-device peak used when the caller doesn't pass one: TPU v5e
-# bf16 peak (mirrors benchmarks/analytic.py TPU_V5E — not importable from
-# src/).  Override with ``peak_flops_per_device=`` for real hardware.
-DEFAULT_PEAK_FLOPS = 197e12
+# Published bf16 peak FLOP/s per chip, keyed by ``device.device_kind``.
+#   "TPU v5 lite": TPU v5e, 197 TFLOP/s (Google Cloud documentation, "TPU v5e")
+PEAK_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def peak_flops(device_kind: str) -> float:
+    """The table's peak for ``device_kind``; a kind it lacks is an error
+    (pass an explicit peak instead), never a default."""
+    if device_kind not in PEAK_FLOPS:
+        raise ValueError(f"no published peak FLOP/s for device kind "
+                         f"{device_kind!r}; pass the peak explicitly "
+                         f"(--peak-flops)")
+    return PEAK_FLOPS[device_kind]
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +129,7 @@ def device_memory() -> Dict:
 class TrainTelemetry:
     def __init__(self, cfg, layout, *, global_batch: int, seq_len: int,
                  warmup_steps: int = 1,
-                 peak_flops_per_device: float = DEFAULT_PEAK_FLOPS,
+                 peak_flops_per_device: Optional[float] = None,
                  mem_every: int = 1, clock=time.perf_counter, tracer=None):
         from ..models import registry
         from .trace import NULL
@@ -130,6 +139,9 @@ class TrainTelemetry:
         self.flops_per_step = (registry.train_flops_per_token(cfg, seq_len)
                                * global_batch * seq_len)
         self.n_devices = layout.n_devices
+        if peak_flops_per_device is None:
+            peak_flops_per_device = peak_flops(
+                layout.mesh.devices.flat[0].device_kind)
         self.peak = float(peak_flops_per_device)
         self.mem_every = max(mem_every, 1)
         self._clock = clock
@@ -231,7 +243,7 @@ class TrainTelemetry:
             f"steady {s['t_step_s']:.3f}s/step)",
             f"  {s['tokens_per_s']:.0f} tok/s   "
             f"MFU {s['mfu']*100:.2f}% of {s['n_devices']}x"
-            f"{s['peak_flops_per_device']:.0e} FLOP/s (nominal)",
+            f"{s['peak_flops_per_device']:.0e} FLOP/s",
             f"  mem watermark {mem:.1f} MiB/device [{s['mem_source']}]",
         ]
         if s["nonfinite"] is not None:

@@ -15,8 +15,9 @@ Sharding contract (driven by ``Layout.zero_stage``, set via
     parameter's own spec *extended by the data axes* ('pod', 'dp') on the
     largest evenly-divisible dim, so each data replica stores and updates
     a 1/(pod*dp) shard.  Gradients are
-    reduce-scattered onto that shard (a GSPMD constraint, see
-    ``core.compat.sharding_constraint``) before the elementwise update.
+    reduce-scattered onto that shard before the elementwise update: a
+    ``with_sharding_constraint`` from the dp-replicated spec to the
+    dp-extended one lowers the dp all-reduce into a reduce-scatter.
     With stage 0 the state simply mirrors the parameter specs (replicated
     over dp).  A dim divisible by neither stays on the parameter spec
     (falls back to replication for that leaf).
@@ -157,7 +158,7 @@ def opt_state_abstract(param_tree, layout: Layout, cfg: OptimConfig):
 def adamw_init(param_tree, layout: Layout, cfg: OptimConfig):
     from ..core.params import init_params
     return init_params(opt_state_abstract(param_tree, layout, cfg),
-                       jax.random.key(0))
+                       jax.random.key(0), layout=layout)
 
 
 adafactor_init = adamw_init
@@ -188,8 +189,8 @@ def make_optimizer(cfg: OptimConfig, layout: Layout, param_tree=None):
     update is computed on the dp-sharded view (grads arrive via a GSPMD
     reduce-scatter), the new moments stay on their shard, and only the
     updated parameter is re-gathered (see the module docstring contract)."""
-    from ..core.compat import sharding_constraint
     sched = make_schedule(cfg)
+    wsc = jax.lax.with_sharding_constraint
     zspecs = None
     if param_tree is not None and layout.effective_zero_stage() >= 1:
         from ..core.params import tree_map_params
@@ -199,10 +200,8 @@ def make_optimizer(cfg: OptimConfig, layout: Layout, param_tree=None):
     def _z(tree):
         if zspecs is None:
             return tree
-        import jax as _jax
-        return _jax.tree.map(
-            lambda a, sp: sharding_constraint(a, layout.sharding(sp)),
-            tree, zspecs)
+        return jax.tree.map(
+            lambda a, sp: wsc(a, layout.sharding(sp)), tree, zspecs)
 
     def adamw_update(params, grads, state: OptState):
         step = state.step + 1
@@ -243,8 +242,7 @@ def make_optimizer(cfg: OptimConfig, layout: Layout, param_tree=None):
             from ..core.params import tree_map_params
             pspecs = tree_map_params(lambda p: p.spec, param_tree)
             new_p = jax.tree.map(
-                lambda a, sp: sharding_constraint(a, layout.sharding(sp)),
-                new_p, pspecs)
+                lambda a, sp: wsc(a, layout.sharding(sp)), new_p, pspecs)
         return new_p, OptState(step, new_m, new_v), {"lr": lr, "gnorm": gnorm}
 
     def adafactor_update(params, grads, state: OptState):

@@ -145,7 +145,7 @@ class Engine:
             tree = kvcache.cache_with_dtype(
                 transformer.abstract_cache(cfg, layout, batch_size, max_len),
                 dtype)
-            self.cache = init_params(tree, jax.random.key(0))
+            self.cache = init_params(tree, jax.random.key(0), layout=layout)
             self._build_contiguous()
 
     # ------------------------------------------------------------------

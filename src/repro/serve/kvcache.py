@@ -32,8 +32,8 @@ block 1 is the *trash* block, the write target for masked-out lanes
 Sharding: pool leaves drop the cache's batch/length sharding (the physical
 dim is replicated over the data and sequence axes) and keep the trailing
 head sharding, so the gather/scatter ops are plain GSPMD gathers — no new
-shard_map regions (jax 0.4.37-safe; the attention islands inside
-``forward`` reshard the views to their own specs).
+shard_map regions (the attention islands inside ``forward`` reshard the
+views to their own specs).
 """
 from __future__ import annotations
 
@@ -387,6 +387,7 @@ class PagedKVCache:
                 f"{cfg.arch}: prefix sharing needs a non-wrapping view "
                 f"(view {l_abs} < max_len {max_len}: the sliding-window "
                 "ring would decode over shared blocks)")
+        self.layout = layout
         self.block = block
         self.blocks_per_slot = -(-l_abs // block)
         self.view_len = self.blocks_per_slot * block
@@ -430,7 +431,8 @@ class PagedKVCache:
     def init_pool(self):
         """Materialize the zeroed pool (positions start at -1: every block,
         including the null block, is invalid until written)."""
-        return init_params(self._abstract_pool, jax.random.key(0))
+        return init_params(self._abstract_pool, jax.random.key(0),
+                           layout=self.layout)
 
     # ---- admission / eviction -------------------------------------------
     def blocks_needed(self, n_tokens: int) -> int:

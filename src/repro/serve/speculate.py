@@ -96,7 +96,7 @@ class DraftSpec:
         tree = kvcache.cache_with_dtype(
             transformer.abstract_cache(cfg, layout, batch_size,
                                        self.cache_len), dtype)
-        self.cache = init_params(tree, jax.random.key(0))
+        self.cache = init_params(tree, jax.random.key(0), layout=layout)
         L = self.cache_len
 
         def prefill_step(params, cache, tokens, length):
@@ -194,10 +194,12 @@ def make_verify(cfg: ModelConfig, layout: Layout, block: int, gamma: int,
 
     ``tokens`` (B, s_pad) is built host-side by the engine —
     ``[t0, d_1..d_γ, 0-pad]`` — NOT assembled on device from ``drafts``:
-    on a multi-device mesh the jax-0.4.x partitioner mis-reshards a
-    concatenate whose consumer (the extend forward) imposes a sharded
-    layout, summing the token ids across replicas (the same bug class as
-    the cross-sharding label concat in the vision-language loss)."""
+    on a multi-device mesh the partitioner of jax 0.4.37, where this was
+    found, mis-reshards a concatenate whose consumer (the extend forward)
+    imposes a sharded layout, summing the token ids across replicas (the
+    same bug class as the cross-sharding label concat in the
+    vision-language loss).  Whether the installed jax still does is not
+    rechecked."""
 
     def verify(params, pool, tokens, drafts, qprobs, offset, length, tables,
                phys_map, limit, key):

@@ -66,8 +66,7 @@ def make_train_step(cfg: ModelConfig, layout: Layout, opt_cfg: OptimConfig):
     def _scatter(gtree):
         if zshards is None:
             return gtree
-        from ..core.compat import sharding_constraint
-        return jax.tree.map(sharding_constraint, gtree, zshards)
+        return jax.tree.map(jax.lax.with_sharding_constraint, gtree, zshards)
 
     def loss_fn(p, b):
         loss, metrics = transformer.forward(cfg, layout, p, b, mode="train")

@@ -26,7 +26,8 @@ for arch in ("mixtral-8x7b", "zamba2-1.2b", "xlstm-350m"):
     # long_500k-style layout: batch unsharded, cache over ('dp', z)
     layc = make_layout(1, 2, 4, "3d", cube=(1, 1, 4),
                        batch_axes=(), seq_axes=("dp",))
-    params = transformer.init(cfg, lay1, jax.random.key(0))
+    # host copy of the single-device init: each layout's jit places it
+    params = jax.device_get(transformer.init(cfg, lay1, jax.random.key(0)))
     T, B, L = 6, 1, 64
     toks = jax.random.randint(jax.random.key(7), (B, T), 0, cfg.vocab)
 

@@ -71,7 +71,8 @@ layouts = {
 B2, S2 = 4, 64
 for arch in ARCH_IDS:
     cfg = reduced(get(arch))
-    params = transformer.init(cfg, lay1, jax.random.key(0))
+    # host copy of the single-device init: each layout's jit places it
+    params = jax.device_get(transformer.init(cfg, lay1, jax.random.key(0)))
     toks = jax.random.randint(jax.random.key(3), (B2, S2), 0, cfg.vocab)
     labs = jax.random.randint(jax.random.key(4), (B2, S2), 0, cfg.vocab)
     batch = {"tokens": toks, "labels": labs}

@@ -231,7 +231,8 @@ def test_telemetry_two_step_train():
 
     tracer = Tracer(annotate=False)
     tel = TrainTelemetry(cfg, lay, global_batch=2, seq_len=32,
-                         warmup_steps=1, tracer=tracer)
+                         warmup_steps=1, peak_flops_per_device=197e12,
+                         tracer=tracer)
     for i in range(2):
         params, opt_state, metrics = step(params, opt_state, batch)
         rec = tel.record(i, metrics)
@@ -263,6 +264,26 @@ def test_telemetry_two_step_train():
     assert "params:" in blame and "all finite" not in blame
 
 
+def test_telemetry_peak_from_device_kind():
+    """The MFU peak comes from the published table by device kind; a kind
+    the table lacks (the CPU) is an error unless a peak is passed."""
+    from repro.configs.registry import get
+    from repro.config import reduced
+    from repro.core.topology import single_device_layout
+    from repro.obs.telemetry import TrainTelemetry, peak_flops
+
+    assert peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(ValueError, match="device kind"):
+        peak_flops("cpu")
+    cfg = reduced(get("tinyllama-1.1b"))
+    with pytest.raises(ValueError, match="--peak-flops"):
+        TrainTelemetry(cfg, single_device_layout(), global_batch=2,
+                       seq_len=16)
+    tel = TrainTelemetry(cfg, single_device_layout(), global_batch=2,
+                         seq_len=16, peak_flops_per_device=1e12)
+    assert tel.peak == 1e12
+
+
 def test_telemetry_write(tmp_path):
     from repro.configs.registry import get
     from repro.config import reduced
@@ -272,7 +293,8 @@ def test_telemetry_write(tmp_path):
     plan = ParallelPlan(n_dp=1, n_model=1)
     plan.validate(n_layers=cfg.n_layers, global_batch=2)
     from repro.obs.telemetry import TrainTelemetry
-    tel = TrainTelemetry(cfg, plan.build(), global_batch=2, seq_len=16)
+    tel = TrainTelemetry(cfg, plan.build(), global_batch=2, seq_len=16,
+                         peak_flops_per_device=197e12)
     path = tmp_path / "tel.json"
     tel.write(str(path))
     doc = json.loads(path.read_text())

@@ -136,7 +136,7 @@ BATTERY = r"""
 import jax, jax.numpy as jnp
 from repro.config import OptimConfig, reduced
 from repro.configs.registry import get
-from repro.core.params import init_params
+from repro.core.params import init_params, shardings
 from repro.core.plan import ParallelPlan
 from repro.models import registry, transformer
 from repro.optim.optimizers import opt_state_abstract
@@ -164,6 +164,10 @@ for arch in ("mixtral-8x7b", "xlstm-350m"):
         if plan.n_stages > 1:
             params["stack"] = registry.repartition_stack(
                 cfg, params0["stack"], lay_ref, lay)
+        # init placed the canonical params on lay_ref's devices; move the
+        # (re-cut) tree onto this plan's mesh
+        params = jax.device_put(params, shardings(
+            transformer.abstract_params(cfg, lay), lay))
         opt_state = init_params(opt_state_abstract(
             transformer.abstract_params(cfg, lay), lay, opt_cfg),
             jax.random.key(1))
@@ -212,7 +216,7 @@ import dataclasses
 import jax, jax.numpy as jnp
 from repro.config import OptimConfig, reduced
 from repro.configs.registry import get
-from repro.core.params import init_params
+from repro.core.params import init_params, shardings
 from repro.core.plan import ParallelPlan
 from repro.models import registry, transformer
 from repro.optim.optimizers import opt_state_abstract
@@ -239,6 +243,8 @@ for name, plan in plans.items():
     if plan.n_stages > 1:
         params["stack"] = registry.repartition_stack(cfg, params0["stack"],
                                                      lay_ref, lay)
+    params = jax.device_put(params, shardings(
+        transformer.abstract_params(cfg, lay), lay))
     opt_state = init_params(opt_state_abstract(
         transformer.abstract_params(cfg, lay), lay, opt_cfg),
         jax.random.key(1))
