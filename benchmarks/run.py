@@ -1,6 +1,6 @@
 """Benchmark harness — one function per paper table/figure + roofline.
 
-``python -m benchmarks.run [table1|table2|comm|kernels|minirun|ppsweep|zerosweep|servesweep|overlapsweep|obssweep|roofline|all]``
+``python -m benchmarks.run [table1|table2|comm|kernels|minirun|ppsweep|zerosweep|servesweep|overlapsweep|roofline|all]``
 
 Prints ``name,us_per_call,derived`` CSV rows per the harness contract:
 derived entries carry the model-based quantity (step time / comm bytes /
@@ -806,154 +806,6 @@ def overlapsweep():
 
 
 # ---------------------------------------------------------------------------
-# Obs sweep: tracer/telemetry overhead on the train step, 8 host devices.
-# One compiled step, three instrumentation modes over identical work:
-# baseline (no tracer object at all), disabled (NULL tracer spans on the hot
-# path — the "pass a tracer everywhere" cost), enabled (recording tracer +
-# per-step telemetry).  The enabled run writes trace artifacts which are
-# validated with tools/check_trace.py.
-# ---------------------------------------------------------------------------
-OBSSWEEP_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time, json, dataclasses, statistics
-sys.path.insert(0, %(src)r)
-import jax
-from repro.config import OptimConfig, ShapeConfig, reduced
-from repro.configs.registry import get
-from repro.core.params import init_params
-from repro.core.plan import ParallelPlan
-from repro.data.pipeline import TokenStream
-from repro.models import transformer
-from repro.obs import make_tracer
-from repro.obs.telemetry import TrainTelemetry
-from repro.optim.optimizers import opt_state_abstract
-from repro.train.step import make_train_step
-
-cfg = dataclasses.replace(reduced(get("tinyllama-1.1b"), d_model=256),
-                          n_layers=2, remat=False)
-opt_cfg = OptimConfig(lr=1e-3, warmup=2, total_steps=100)
-plan = ParallelPlan(n_dp=1, n_model=8, cube=(2, 2, 2))
-plan.validate(n_layers=cfg.n_layers, global_batch=8)
-lay = plan.build()
-params = transformer.init(cfg, lay, jax.random.key(0))
-opt_state = init_params(opt_state_abstract(
-    transformer.abstract_params(cfg, lay), lay, opt_cfg),
-    jax.random.key(1))
-shape = ShapeConfig("o", 128, 8, "train")
-batch = next(iter(TokenStream(cfg, lay, shape)))
-step = jax.jit(make_train_step(cfg, lay, opt_cfg))
-p, o, m = step(params, opt_state, batch)     # compile once, shared by all
-jax.block_until_ready(m["loss"])
-
-N = 10
-tracer = make_tracer(True)
-# the CPU has no published peak: MFU here is against the v5e figure, a
-# label for comparing these CPU runs with each other, not a device metric
-tel = TrainTelemetry(cfg, lay, global_batch=8, seq_len=128, warmup_steps=0,
-                     peak_flops_per_device=197e12, tracer=tracer)
-
-def step_baseline(p, o, i):
-    p, o, m = step(p, o, batch)
-    jax.block_until_ready(m["loss"])
-    return p, o
-
-def make_traced(tr, t):
-    def step_traced(p, o, i):
-        with tr.span("train_step", track="train", step=i) as sp:
-            p, o, m = step(p, o, batch)
-            sp.sync(m["loss"])
-        # the NULL span's sync is deliberately a no-op, so the disabled
-        # mode must still pay the same device wait as the others or its
-        # dispatched work bleeds into the next mode's timing
-        jax.block_until_ready(m["loss"])
-        if t is not None:
-            t.record(i, m)
-        return p, o
-    return step_traced
-
-# interleave the modes round-robin so host-load drift over the run hits
-# every mode equally — sequential blocks would attribute drift to whichever
-# mode ran last
-modes = {"baseline": step_baseline,
-         "disabled": make_traced(make_tracer(False), None),
-         "enabled": make_traced(tracer, tel)}
-states = {name: (params, opt_state) for name in modes}
-out = {name: {"t_steps": []} for name in modes}
-for i in range(N + 1):
-    for name, fn in modes.items():
-        p, o = states[name]
-        t0 = time.perf_counter()
-        states[name] = fn(p, o, i)
-        if i > 0:                            # round 0 is a warm-up round
-            out[name]["t_steps"].append(time.perf_counter() - t0)
-for r in out.values():
-    r["t_step_median"] = statistics.median(r["t_steps"])
-    # min over interleaved reps is the low-noise cost estimate: host-load
-    # spikes only ever add time, and they land on random rounds
-    r["t_step_min"] = min(r["t_steps"])
-tracer.write_chrome(%(trace)r)
-tracer.write_jsonl(%(trace)r + ".jsonl")
-s = tel.summary()
-out["telemetry"] = {k: s[k] for k in ("tokens_per_s", "mfu", "mem_source",
-                                      "mem_peak_bytes_max", "n_devices")}
-print("RESULT " + json.dumps(out))
-"""
-
-
-def obssweep():
-    import tempfile
-    env = _host_env()
-    tmp = tempfile.mkdtemp(prefix="obssweep_")
-    trace = os.path.join(tmp, "trace.json")
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         OBSSWEEP_SCRIPT % {"src": os.path.join(ROOT, "src"),
-                            "trace": trace}],
-        env=env, capture_output=True, text=True, timeout=3000)
-    for line in proc.stdout.splitlines():
-        if not line.startswith("RESULT "):
-            continue
-        res = json.loads(line[len("RESULT "):])
-        check = subprocess.run(
-            [sys.executable, os.path.join(ROOT, "tools", "check_trace.py"),
-             trace, trace + ".jsonl"], capture_output=True, text=True)
-        # gate on the min over interleaved reps, not the median: on a
-        # shared CPU box the median still carries ~10% contention noise,
-        # the min is stable (noise only ever adds time)
-        base = res["baseline"]["t_step_min"]
-        for name in ("baseline", "disabled", "enabled"):
-            r = res[name]
-            _row(f"obssweep_train_step|{name}|3d8|8hostdev",
-                 f"{r['t_step_min']*1e6:.0f}",
-                 f"overhead={r['t_step_min']/base - 1:+.3%} "
-                 f"median={r['t_step_median']*1e6:.0f}us")
-        crit = {
-            "disabled_overhead": res["disabled"]["t_step_min"] / base - 1,
-            "tracer_overhead": res["enabled"]["t_step_min"] / base - 1,
-            "disabled_overhead_le_1pct":
-                res["disabled"]["t_step_min"] / base - 1 <= 0.01,
-            "tracer_overhead_le_5pct":
-                res["enabled"]["t_step_min"] / base - 1 <= 0.05,
-            "trace_artifacts_valid": check.returncode == 0,
-        }
-        _row("obssweep|criteria", "",
-             f"disabled={crit['disabled_overhead']:+.3%} (<=1% "
-             f"{crit['disabled_overhead_le_1pct']}) "
-             f"enabled={crit['tracer_overhead']:+.3%} (<=5% "
-             f"{crit['tracer_overhead_le_5pct']}) "
-             f"trace_valid={crit['trace_artifacts_valid']}")
-        res["criteria"] = crit
-        res["plan"] = {"strategy": "3d", "n_model": 8, "cube": [2, 2, 2],
-                       "host_devices": 8, "steps_per_mode": 8}
-        res["trace_artifact"] = trace
-        return res
-    print(proc.stderr[-2000:], file=sys.stderr)
-    _row("obssweep", "", "FAILED")
-    return None
-
-
-# ---------------------------------------------------------------------------
 # Roofline from the dry-run results
 # ---------------------------------------------------------------------------
 def roofline(path=None):
@@ -990,8 +842,7 @@ def main() -> None:
     scenarios = {"table1": table1, "table2": table2, "comm": comm_volume,
                  "kernels": kernels, "minirun": minirun, "ppsweep": ppsweep,
                  "zerosweep": zerosweep, "servesweep": servesweep,
-                 "overlapsweep": overlapsweep, "obssweep": obssweep,
-                 "roofline": roofline}
+                 "overlapsweep": overlapsweep, "roofline": roofline}
     print("name,us_per_call,derived")
     for name, fn in scenarios.items():
         if which not in (name, "all"):

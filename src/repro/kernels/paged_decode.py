@@ -177,6 +177,7 @@ def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(tables.astype(jnp.int32), cur.astype(jnp.int32), qr, kr, vr, pr)
     if residuals:
         return (out.reshape(B, nq, dv), m.reshape(B, nq), l.reshape(B, nq))

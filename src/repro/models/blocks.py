@@ -12,6 +12,7 @@ restored at block exit (paper §3.2).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -29,6 +30,19 @@ from ..core.topology import Dirs, Layout
 
 F32 = jnp.float32
 NEG_INF = -1e30
+
+
+def _scoped(name: str):
+    """Run the decorated sub-block under ``jax.named_scope(name)``:
+    metadata only, so that each op of the compiled program names the
+    sub-block (``attention``, ``mlp``, ``norm``) it came from."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return inner
+    return deco
 
 
 # ---------------------------------------------------------------------------
@@ -599,6 +613,7 @@ def mlp_params(layout: Layout, cfg: ModelConfig, dirs: Dirs, d_ff=None, fsdp=Fal
     return p
 
 
+@_scoped("attention")
 def attn_apply(layout: Layout, cfg: ModelConfig, dirs: Dirs, x, p, positions,
                *, causal=True, window=0, decode=False, cache=None,
                kv_override=None, return_kv=False, page=None):
@@ -692,6 +707,7 @@ def _cross_decode(layout, cfg, dirs, q, k, v):
                          out_specs=qspec, check_vma=False)(q, k, v)
 
 
+@_scoped("mlp")
 def mlp_apply(layout: Layout, cfg: ModelConfig, dirs: Dirs, x, p, decode=False):
     act = _act_fn(cfg.act)
     up, d2 = plinear(layout, dirs, x, p["w_up"], kind="first", decode=decode)
@@ -713,6 +729,7 @@ def make_norm_params(layout: Layout, cfg: ModelConfig, dirs: Dirs, d=None):
     return p
 
 
+@_scoped("norm")
 def apply_norm(cfg: ModelConfig, x, p):
     if cfg.norm == "layernorm":
         return layernorm(x, p["g"], p["b"])

@@ -10,8 +10,7 @@ loss/grad-norm series, and a non-finite sentinel.
 
 ``record`` is an explicit sync point (it blocks on the loss so the step
 time covers device work, not dispatch) — per-step telemetry is therefore
-*not* free; the tracer's ``obssweep`` benchmark measures exactly this cost
-and CI gates it at <= 5%.
+*not* free.
 
 What it accounts:
 

@@ -28,8 +28,17 @@ Design constraints (the reason this is not a logging wrapper):
     exceptions: a span whose body raises is still emitted, tagged with
     ``error=<ExceptionType>``.
   * ``annotate=True`` (default) additionally wraps each span in
-    ``jax.profiler.TraceAnnotation`` so the same names land inside XLA
-    profiles when one is being captured.
+    ``jax.profiler.TraceAnnotation``, with the span's entry args, so the
+    same names and args land inside XLA profiles when one is being
+    captured.
+  * profiler-only mode: ``PROFILE`` is a :class:`ProfileTracer` whose
+    ``span()`` is that ``TraceAnnotation`` and nothing else — no clock
+    read, no event kept in Python.  The profiler records the span only
+    while a trace is captured, on the host plane of the same session as
+    the device ops, so it shares the device trace's clock.  A server that
+    never stops runs with it (the serve engine's default).
+  * ``COMPILES`` counts backend compiles and persistent-cache loads (one
+    ``jax.monitoring`` listener), process-wide, for spans to carry.
 
 Export: ``write_jsonl(path)`` and ``write_chrome(path)``; the Chrome file
 loads in ``chrome://tracing`` / Perfetto (``ph:"X"`` complete events, one
@@ -74,11 +83,6 @@ class NullTracer:
     def span(self, name, track="main", annotate=None, **args):
         return _NULL_SPAN
 
-    def traced(self, name=None, track="main"):
-        def deco(fn):
-            return fn
-        return deco
-
     def instant(self, name, track="main", **args):
         pass
 
@@ -104,6 +108,24 @@ class NullTracer:
 NULL = NullTracer()
 
 
+class ProfileTracer(NullTracer):
+    """Profiler-only tracer: ``span`` is ``jax.profiler.TraceAnnotation``
+    with the span's args and does nothing else; every other method is the
+    disabled tracer's no-op.  With no trace being captured a span costs
+    the annotation's construction and two calls."""
+
+    _annotation = None
+
+    def span(self, name, track="main", annotate=None, **args):
+        if self._annotation is None:      # jax is imported on first use
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        return self._annotation(name, **args)
+
+
+PROFILE = ProfileTracer()
+
+
 class _Span:
     __slots__ = ("_tr", "name", "track", "args", "t0", "_ann")
 
@@ -114,7 +136,7 @@ class _Span:
         self.track = track
         self.args = args
         self.t0 = 0.0
-        self._ann = tracer._annotation(name) if annotate else None
+        self._ann = tracer._annotation(name, args) if annotate else None
 
     def __enter__(self):
         if self._ann is not None:
@@ -180,8 +202,10 @@ class Tracer:
         outside the tracer, e.g. serve/metrics.py request stamps)."""
         return t_abs - self._t0
 
-    def _annotation(self, name):
-        return self._ann_cls(name) if self._ann_cls is not None else None
+    def _annotation(self, name, args):
+        if self._ann_cls is None:
+            return None
+        return self._ann_cls(name, **args)
 
     def _emit(self, ev: dict):
         with self._lock:
@@ -193,20 +217,6 @@ class Tracer:
         """Context manager timing its body.  ``with tracer.span("step"):``"""
         ann = self.annotate if annotate is None else annotate
         return _Span(self, name, track, ann, args)
-
-    def traced(self, name: Optional[str] = None, track: str = "main"):
-        """Decorator form: ``@tracer.traced()`` spans every call."""
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            def wrapper(*a, **kw):
-                with self.span(label, track=track):
-                    return fn(*a, **kw)
-            wrapper.__name__ = fn.__name__
-            wrapper.__qualname__ = fn.__qualname__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco
 
     def instant(self, name: str, track: str = "main", **args):
         ev = {"ev": "instant", "name": name, "track": track, "ts": self.now()}
@@ -270,3 +280,39 @@ class Tracer:
 def make_tracer(enabled: bool, **kw):
     """``Tracer(**kw)`` when enabled, the shared ``NULL`` otherwise."""
     return Tracer(**kw) if enabled else NULL
+
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class CompileCounter:
+    """Count and summed seconds of the process's backend compiles.
+
+    JAX records ``COMPILE_EVENT`` around ``compile_or_get_cached``, so a
+    persistent-cache load counts as well as a compile.  ``watch()``
+    registers the one listener (idempotent); ``n`` and ``s`` only grow.
+    A server that compiles under traffic shows it here."""
+
+    def __init__(self):
+        self.n = 0
+        self.s = 0.0
+        self._lock = threading.Lock()
+        self._watching = False
+
+    def _on_duration(self, event: str, secs: float, **_):
+        if event == COMPILE_EVENT:
+            with self._lock:
+                self.n += 1
+                self.s += secs
+
+    def watch(self) -> "CompileCounter":
+        with self._lock:
+            if not self._watching:
+                import jax.monitoring
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_duration)
+                self._watching = True
+        return self
+
+
+COMPILES = CompileCounter()

@@ -40,7 +40,7 @@ from ..core.params import init_params
 from ..core.topology import Layout
 from ..models import blocks as B
 from ..models import registry, transformer
-from ..obs.trace import NULL
+from ..obs.trace import COMPILES, PROFILE
 from . import kvcache, sampling, speculate
 from .metrics import ServeMetrics
 from .scheduler import Scheduler, pad_bucket
@@ -78,9 +78,12 @@ class Engine:
                  tracer=None):
         self.cfg, self.layout, self.params = cfg, layout, params
         # observability: per-request lifecycle spans are emitted by the
-        # metrics hooks; the engine itself adds one span per device tick on
-        # the "engine" lane.  The default NULL tracer makes all of it free.
-        self.tracer = tracer if tracer is not None else NULL
+        # metrics hooks; the engine itself adds the serve.* spans of each
+        # step on the "engine" lane.  The default PROFILE tracer keeps
+        # nothing in Python: its spans reach a profile only while one is
+        # being captured (jax.profiler.start_trace).
+        self.tracer = tracer if tracer is not None else PROFILE
+        COMPILES.watch()
         self.B, self.max_len = batch_size, max_len
         self.temperature = temperature
         self.paged = registry.serve_cache_mode(cfg) == "paged"
@@ -337,187 +340,220 @@ class Engine:
     # ------------------------------------------------------------------
     def step(self):
         """One engine step: admit waiting work, then either one chunked
-        prefill group or one global decode tick."""
-        self._admit()
+        prefill group or one global decode tick.
+
+        Spans, all on the ``engine`` track: ``serve.step`` (queue depth,
+        occupied slots, and the process's compile count and seconds, at
+        entry) holds in order ``serve.admit``, ``serve.prepare`` (the
+        host builds the program's inputs), ``serve.dispatch`` (the jitted
+        call, with any trace or compile), ``serve.wait`` (the one
+        ``device_get`` of the sampled tokens) and ``serve.emit`` (tokens,
+        metrics hooks, finished requests)."""
         tr = self.tracer
-        if self.chunked and self.scheduler.pending_prefill:
-            with tr.span("prefill_tick", track="engine"):
+        with tr.span("serve.step", track="engine",
+                     queue=self.scheduler.queue_depth(),
+                     slots=sum(s is not None for s in self.slots),
+                     compile_n=COMPILES.n, compile_s=COMPILES.s):
+            with tr.span("serve.admit", track="engine"):
+                self._admit()
+            if self.chunked and self.scheduler.pending_prefill:
                 self._prefill_tick()
-            kind = "prefill"
-        elif self.spec is not None:
-            with tr.span("spec_tick", track="engine"):
+                kind = "prefill"
+            elif self.spec is not None:
                 self._spec_tick()
-            kind = "decode"
-        else:
-            with tr.span("decode_tick", track="engine"):
+                kind = "decode"
+            else:
                 self._decode_tick()
-            kind = "decode"
-        self.metrics.observe_step(self.scheduler.queue_depth(), kind)
-        if tr.enabled:
-            tr.counter("active_slots",
-                       sum(s is not None for s in self.slots),
-                       track="engine")
+                kind = "decode"
+            self.metrics.observe_step(self.scheduler.queue_depth(), kind)
         self.steps += 1
 
     def _prefill_tick(self):
         # with the prefix cache on, each slot only prefills its un-hit
         # tail: grouping / padding / the token budget all run on the tail
         # length, which is where the TTFT win comes from
+        tr = self.tracer
         lens = {s: len(self.slots[s].prompt)
                 - (self.kv.hit_len(s) if self.prefix else 0)
                 for s in self.scheduler.pending_prefill}
         group, s_pad = self.scheduler.prefill_group(lens)
-        tokens = np.zeros((self.B, s_pad), np.int32)
-        length = np.zeros((self.B,), np.int32)
-        if self.prefix:
-            offset = np.zeros((self.B,), np.int32)
-            for s in group:
-                p = self.slots[s].prompt
-                hit = self.kv.hit_len(s)
-                tokens[s, :len(p) - hit] = p[hit:]
-                offset[s] = hit
-                length[s] = len(p) - hit
-            phys_map = self.kv.extend_phys_map(
-                {s: (int(offset[s]), int(length[s])) for s in group}, s_pad)
-            tok, self.pool = self._extendf(
-                self.params, self.pool, jnp.asarray(tokens),
-                jnp.asarray(offset), jnp.asarray(length),
-                self.kv.tables_device(), phys_map, self._split_key())
-        else:
-            for s in group:
-                p = self.slots[s].prompt
-                tokens[s, :len(p)] = p
-                length[s] = len(p)
-            phys_map = self.kv.prefill_phys_map(
-                {s: lens[s] for s in group}, s_pad)
-            tok, self.pool = self._prefill(self.params, self.pool,
-                                           jnp.asarray(tokens),
-                                           jnp.asarray(length), phys_map,
-                                           self._split_key())
-        if self.spec is not None:
-            # the draft prefills the FULL prompt into its private cache —
-            # its cache has no prefix sharing, and the propose bursts need
-            # the whole context resident
-            d_pad = pad_bucket(max(len(self.slots[s].prompt) for s in group))
-            dtok = np.zeros((self.B, d_pad), np.int32)
-            dlen = np.zeros((self.B,), np.int32)
-            for s in group:
-                p = self.slots[s].prompt
-                dtok[s, :len(p)] = p
-                dlen[s] = len(p)
-            self.spec.prefill(jnp.asarray(dtok), jnp.asarray(dlen))
-        tok = np.asarray(jax.device_get(tok))
-        for s in group:
-            req = self.slots[s]
-            self.pos[s] = len(req.prompt)
-            req._fed = len(req.prompt)
+        with tr.span("serve.prepare", track="engine", rows=len(group),
+                     tokens=sum(lens[s] for s in group),
+                     padded=self.B * s_pad):
+            tokens = np.zeros((self.B, s_pad), np.int32)
+            length = np.zeros((self.B,), np.int32)
             if self.prefix:
-                # publish this prompt's full blocks before any possible
-                # release below — completed requests still seed the index
-                self.kv.register_prefix(s)
-            req.out.append(int(tok[s]))
-            self.metrics.token(req.uid)
-            if len(req.out) >= req.max_new or self.pos[s] >= self.max_len - 1:
-                self._finish(s)
+                offset = np.zeros((self.B,), np.int32)
+                for s in group:
+                    p = self.slots[s].prompt
+                    hit = self.kv.hit_len(s)
+                    tokens[s, :len(p) - hit] = p[hit:]
+                    offset[s] = hit
+                    length[s] = len(p) - hit
+                phys_map = self.kv.extend_phys_map(
+                    {s: (int(offset[s]), int(length[s])) for s in group},
+                    s_pad)
+                run = self._extendf
+                args = (jnp.asarray(tokens), jnp.asarray(offset),
+                        jnp.asarray(length), self.kv.tables_device(),
+                        phys_map)
+            else:
+                for s in group:
+                    p = self.slots[s].prompt
+                    tokens[s, :len(p)] = p
+                    length[s] = len(p)
+                phys_map = self.kv.prefill_phys_map(
+                    {s: lens[s] for s in group}, s_pad)
+                run = self._prefill
+                args = (jnp.asarray(tokens), jnp.asarray(length), phys_map)
+            if self.spec is not None:
+                # the draft prefills the FULL prompt into its private cache
+                # — its cache has no prefix sharing, and the propose
+                # bursts need the whole context resident
+                d_pad = pad_bucket(max(len(self.slots[s].prompt)
+                                       for s in group))
+                dtok = np.zeros((self.B, d_pad), np.int32)
+                dlen = np.zeros((self.B,), np.int32)
+                for s in group:
+                    p = self.slots[s].prompt
+                    dtok[s, :len(p)] = p
+                    dlen[s] = len(p)
+                draft = (jnp.asarray(dtok), jnp.asarray(dlen))
+        with tr.span("serve.dispatch", track="engine"):
+            tok, self.pool = run(self.params, self.pool, *args,
+                                 self._split_key())
+            if self.spec is not None:
+                self.spec.prefill(*draft)
+        with tr.span("serve.wait", track="engine"):
+            tok = np.asarray(jax.device_get(tok))
+        with tr.span("serve.emit", track="engine"):
+            for s in group:
+                req = self.slots[s]
+                self.pos[s] = len(req.prompt)
+                req._fed = len(req.prompt)
+                if self.prefix:
+                    # publish this prompt's full blocks before any possible
+                    # release below — completed requests still seed the
+                    # index
+                    self.kv.register_prefix(s)
+                req.out.append(int(tok[s]))
+                self.metrics.token(req.uid)
+                if (len(req.out) >= req.max_new
+                        or self.pos[s] >= self.max_len - 1):
+                    self._finish(s)
 
     def _decode_tick(self):
-        tok = np.zeros((self.B, 1), np.int32)
-        active = np.zeros((self.B,), bool)
+        tr = self.tracer
         pending = set(self.scheduler.pending_prefill)
-        for i, req in enumerate(self.slots):
-            if req is None or i in pending:
-                continue
-            if req._fed < len(req.prompt):
-                tok[i, 0] = req.prompt[req._fed]     # sequential prefill
-                active[i] = True
-            elif req.out:
-                tok[i, 0] = req.out[-1]
-                active[i] = True
-        if not active.any():
+        rows = [i for i, req in enumerate(self.slots)
+                if req is not None and i not in pending
+                and (req._fed < len(req.prompt) or req.out)]
+        if not rows:
             return
-        batch = (jnp.asarray(tok), jnp.asarray(self.pos))
-        if self.paged:
-            nxt, self.pool = self._decode(
-                self.params, self.pool, batch[0], batch[1],
-                self.kv.tables_device(), jnp.asarray(active),
-                self._split_key())
-        else:
-            nxt, self.cache = self._decode(self.params, self.cache,
-                                           batch[0], batch[1],
-                                           self._split_key())
-        nxt = np.asarray(jax.device_get(nxt))
-        for i, req in enumerate(self.slots):
-            if req is None or not active[i]:
-                continue
-            self.pos[i] += 1
-            if req._fed < len(req.prompt):
-                req._fed += 1
+        with tr.span("serve.prepare", track="engine", rows=len(rows),
+                     live=int(self.pos[rows].sum())):
+            tok = np.zeros((self.B, 1), np.int32)
+            active = np.zeros((self.B,), bool)
+            for i in rows:
+                req = self.slots[i]
+                tok[i, 0] = (req.prompt[req._fed]      # sequential prefill
+                             if req._fed < len(req.prompt) else req.out[-1])
+                active[i] = True
+            args = (jnp.asarray(tok), jnp.asarray(self.pos))
+            if self.paged:
+                args += (self.kv.tables_device(), jnp.asarray(active))
+        with tr.span("serve.dispatch", track="engine"):
+            if self.paged:
+                nxt, self.pool = self._decode(self.params, self.pool, *args,
+                                              self._split_key())
+            else:
+                nxt, self.cache = self._decode(self.params, self.cache,
+                                               *args, self._split_key())
+        with tr.span("serve.wait", track="engine"):
+            nxt = np.asarray(jax.device_get(nxt))
+        with tr.span("serve.emit", track="engine"):
+            for i in rows:
+                req = self.slots[i]
+                self.pos[i] += 1
                 if req._fed < len(req.prompt):
-                    continue
-            req.out.append(int(nxt[i]))
-            self.metrics.token(req.uid)
-            if len(req.out) >= req.max_new or self.pos[i] >= self.max_len - 1:
-                self._finish(i)
+                    req._fed += 1
+                    if req._fed < len(req.prompt):
+                        continue
+                req.out.append(int(nxt[i]))
+                self.metrics.token(req.uid)
+                if (len(req.out) >= req.max_new
+                        or self.pos[i] >= self.max_len - 1):
+                    self._finish(i)
 
     def _spec_tick(self):
         """One speculative decode round: the draft bursts γ proposals per
         active slot, the target verifies them in one batched extend, and
-        each row emits ``accepted + 1`` tokens (accepted drafts + bonus)."""
+        each row emits ``accepted + 1`` tokens (accepted drafts + bonus).
+        ``serve.dispatch`` holds the draft's proposals; the hop of the
+        drafts through the host and the verify are inside ``serve.wait``."""
+        tr = self.tracer
         gamma = self.spec.gamma
-        t0 = np.zeros((self.B,), np.int32)
-        tprev = np.zeros((self.B,), np.int32)
-        posv = np.ones((self.B,), np.int32)
-        limit = np.zeros((self.B,), np.int32)
-        active = np.zeros((self.B,), bool)
         pending = set(self.scheduler.pending_prefill)
-        rows = {}
-        for i, req in enumerate(self.slots):
-            if req is None or i in pending or not req.out:
-                continue
-            t0[i] = req.out[-1]
-            tprev[i] = req.out[-2] if len(req.out) >= 2 else req.prompt[-1]
-            posv[i] = self.pos[i]
-            # emit at most limit+1 tokens: stay under max_new AND under the
-            # decode length bound (pos must end < max_len - 1, matching the
-            # non-speculative finish condition)
-            limit[i] = max(min(req.max_new - len(req.out),
-                               self.max_len - 1 - self.pos[i]) - 1, 0)
-            active[i] = True
-            rows[i] = (int(self.pos[i]), gamma + 1)
-        if not active.any():
+        rows = [i for i, req in enumerate(self.slots)
+                if req is not None and i not in pending and req.out]
+        if not rows:
             return
-        drafts, qprobs = self.spec.propose(jnp.asarray(tprev),
-                                           jnp.asarray(t0), jnp.asarray(posv),
-                                           self._split_key())
-        # the draft lives on its own (typically single-device) mesh; its
-        # outputs are committed there — hop through the host so the verify
-        # jit can place them on the target's mesh.  The verify batch
-        # [t0, d_1..d_γ, pad] is assembled here too (see make_verify: a
-        # device-side concatenate mis-reshards on multi-device meshes)
-        drafts = np.asarray(jax.device_get(drafts))
-        qprobs = np.asarray(jax.device_get(qprobs))
-        vtok = np.zeros((self.B, self._spec_pad()), np.int32)
-        vtok[:, 0] = t0
-        vtok[:, 1:gamma + 1] = drafts
-        phys_map = self.kv.extend_phys_map(rows, self._spec_pad())
-        a, emit, self.pool = self._verify(
-            self.params, self.pool, jnp.asarray(vtok), drafts, qprobs,
-            jnp.asarray(posv), jnp.asarray(np.where(active, gamma + 1, 0)
-                                           .astype(np.int32)),
-            self.kv.tables_device(), phys_map, jnp.asarray(limit),
-            self._split_key())
-        a = np.asarray(jax.device_get(a))
-        emit = np.asarray(jax.device_get(emit))
-        for i, req in enumerate(self.slots):
-            if req is None or not active[i]:
-                continue
-            n = int(a[i]) + 1
-            req.out.extend(int(t) for t in emit[i, :n])
-            self.metrics.token(req.uid, n)
-            self.metrics.spec_accept(int(a[i]))
-            self.pos[i] += n
-            if len(req.out) >= req.max_new or self.pos[i] >= self.max_len - 1:
-                self._finish(i)
+        with tr.span("serve.prepare", track="engine", rows=len(rows),
+                     live=int(self.pos[rows].sum())):
+            t0 = np.zeros((self.B,), np.int32)
+            tprev = np.zeros((self.B,), np.int32)
+            posv = np.ones((self.B,), np.int32)
+            limit = np.zeros((self.B,), np.int32)
+            active = np.zeros((self.B,), bool)
+            ext = {}
+            for i in rows:
+                req = self.slots[i]
+                t0[i] = req.out[-1]
+                tprev[i] = (req.out[-2] if len(req.out) >= 2
+                            else req.prompt[-1])
+                posv[i] = self.pos[i]
+                # emit at most limit+1 tokens: stay under max_new AND under
+                # the decode length bound (pos must end < max_len - 1,
+                # matching the non-speculative finish condition)
+                limit[i] = max(min(req.max_new - len(req.out),
+                                   self.max_len - 1 - self.pos[i]) - 1, 0)
+                active[i] = True
+                ext[i] = (int(self.pos[i]), gamma + 1)
+            args = (jnp.asarray(tprev), jnp.asarray(t0), jnp.asarray(posv))
+        with tr.span("serve.dispatch", track="engine"):
+            drafts, qprobs = self.spec.propose(*args, self._split_key())
+        with tr.span("serve.wait", track="engine"):
+            # the draft lives on its own (typically single-device) mesh;
+            # its outputs are committed there — hop through the host so the
+            # verify jit can place them on the target's mesh.  The verify
+            # batch [t0, d_1..d_γ, pad] is assembled here too (see
+            # make_verify: a device-side concatenate mis-reshards on
+            # multi-device meshes)
+            drafts = np.asarray(jax.device_get(drafts))
+            qprobs = np.asarray(jax.device_get(qprobs))
+            vtok = np.zeros((self.B, self._spec_pad()), np.int32)
+            vtok[:, 0] = t0
+            vtok[:, 1:gamma + 1] = drafts
+            phys_map = self.kv.extend_phys_map(ext, self._spec_pad())
+            a, emit, self.pool = self._verify(
+                self.params, self.pool, jnp.asarray(vtok), drafts, qprobs,
+                jnp.asarray(posv), jnp.asarray(np.where(active, gamma + 1, 0)
+                                               .astype(np.int32)),
+                self.kv.tables_device(), phys_map, jnp.asarray(limit),
+                self._split_key())
+            a = np.asarray(jax.device_get(a))
+            emit = np.asarray(jax.device_get(emit))
+        with tr.span("serve.emit", track="engine"):
+            for i in rows:
+                req = self.slots[i]
+                n = int(a[i]) + 1
+                req.out.extend(int(t) for t in emit[i, :n])
+                self.metrics.token(req.uid, n)
+                self.metrics.spec_accept(int(a[i]))
+                self.pos[i] += n
+                if (len(req.out) >= req.max_new
+                        or self.pos[i] >= self.max_len - 1):
+                    self._finish(i)
 
     # ------------------------------------------------------------------
     def _busy(self) -> bool:

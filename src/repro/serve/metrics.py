@@ -3,7 +3,7 @@
 The engine calls the ``submit`` / ``admit`` / ``token`` / ``finish`` /
 ``reject`` hooks as requests move through it and ``observe_step`` once
 per engine step; ``summary()`` reduces everything to a plain dict
-(p50/p95 latencies in seconds, tok/s, queue-depth histogram) and
+(p50/p95 latencies in seconds, tok/s, queue depth) and
 ``format_summary`` renders the launcher's report.  Pure host-side
 bookkeeping — nothing here touches jax.
 
@@ -42,19 +42,6 @@ def percentile(values: List[float], q: float) -> float:
         return 0.0
     return float(np.percentile(vals, min(max(q, 0.0), 100.0),
                                method="nearest"))
-
-
-def histogram(values: List[float], bins: int = 8):
-    """Equal-width histogram -> (edges [bins+1], counts [bins]).  Total on
-    the edge cases: empty/all-non-finite -> ([0, 1], [0]); a single sample
-    or an all-equal series gets a unit-width range centred on the value
-    (numpy's degenerate-range padding) with every count in one bin —
-    callers always see len(edges) == bins + 1, sum(counts) == n_finite."""
-    vals = [v for v in values if math.isfinite(v)]
-    if not vals:
-        return [0.0, 1.0], [0]
-    counts, edges = np.histogram(vals, bins=bins)
-    return edges.tolist(), counts.tolist()
 
 
 class _Track:
@@ -176,10 +163,6 @@ class ServeMetrics:
             "tpot_p50_s": percentile(tpot, 50),
             "tpot_p95_s": percentile(tpot, 95),
             "queue_depth_max": max(self.queue_depths, default=0),
-            "queue_depth_hist": histogram([float(q) for q in
-                                           self.queue_depths]),
-            "ttft_hist": histogram(ttft),
-            "tpot_hist": histogram(tpot),
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
             "prefix_lookups": self.prefix_lookups,
@@ -191,7 +174,6 @@ class ServeMetrics:
             "spec_steps": len(self.accepted),
             "accepted_mean": (float(np.mean(self.accepted))
                               if self.accepted else 0.0),
-            "accepted_hist": histogram([float(a) for a in self.accepted]),
         }
 
 

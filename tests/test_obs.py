@@ -1,6 +1,6 @@
 """Observability layer: tracer no-op/nesting/round-trip contracts, trace
 validators, telemetry on a real 2-step train run (single device), the
-serve-metrics percentile/histogram edge cases, and the commcheck analytic
+serve-metrics percentile edge cases, and the commcheck analytic
 formulas pinned against benchmarks/analytic.py.  The multi-device commcheck
 measurement itself runs as a subprocess on 4 host devices with pinned
 collective counts for the (1, 2, 2) cube.
@@ -19,7 +19,7 @@ sys.path.insert(0, ROOT)                     # benchmarks/, tools/
 from repro.obs import NULL, NullTracer, Tracer, make_tracer  # noqa: E402
 from repro.obs.telemetry import (first_nonfinite_path,  # noqa: E402
                                  nonfinite_report)
-from repro.serve.metrics import histogram, percentile  # noqa: E402
+from repro.serve.metrics import percentile  # noqa: E402
 from tools.check_trace import (validate_chrome,  # noqa: E402
                                validate_events, validate_jsonl)
 
@@ -54,12 +54,6 @@ def test_null_tracer_is_shared_singleton_noop():
     tr.span_at("s", 0.0, 1.0)
     assert tr.events == ()                    # nothing recorded, ever
     assert tr.now() == 0.0 and tr.rel(123.4) == 0.0
-
-    @tr.traced()
-    def fn(x):
-        return x + 1
-
-    assert fn(1) == 2                         # decorator returns fn unwrapped
 
 
 def test_null_tracer_write_is_noop(tmp_path):
@@ -118,6 +112,74 @@ def test_span_at_retroactive():
     assert ev["args"]["tokens"] == 5
     # rel() maps absolute stamps of the same clock into the timebase
     assert abs(tr.rel(tr._t0) - 0.0) < 1e-12
+
+
+# ---------------------------------------------------------------------------
+# Profiler-only mode and the compile counter
+# ---------------------------------------------------------------------------
+def _profiled_spans(log_dir, body):
+    """(name, stats) of the host events of a profiler trace of ``body``."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(e.name, dict(e.stats)) for plane in pd.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+
+
+def test_profile_tracer_spans_land_in_the_profile_only(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import PROFILE, ProfileTracer
+    assert isinstance(PROFILE, ProfileTracer) and PROFILE.enabled is False
+    recording = Tracer(clock=FakeClock(), annotate=True)
+    x = jnp.ones(4)
+
+    def body():
+        with PROFILE.span("serve.step", track="engine", queue=3,
+                          compile_s=0.25):
+            (x + 1).block_until_ready()
+        with recording.span("train_step", track="train", step=4):
+            pass
+        PROFILE.instant("i")
+        PROFILE.counter("c", 1.0)
+        PROFILE.span_at("s", 0.0, 1.0)
+
+    evs = _profiled_spans(tmp_path, body)
+    assert ("serve.step", {"queue": 3, "compile_s": 0.25}) in evs
+    # the recording tracer puts the same name and args in the profile
+    assert ("train_step", {"step": 4}) in evs
+    assert not any(n in ("i", "c", "s") for n, _ in evs)
+    assert PROFILE.events == () and PROFILE.now() == 0.0
+    assert [e["name"] for e in recording.events] == ["train_step"]
+
+
+def test_compile_counter_counts_backend_compiles():
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import COMPILES
+    from repro.obs.trace import COMPILE_EVENT
+    assert COMPILES.watch() is COMPILES.watch()       # one listener
+    x = jnp.arange(3.0)
+    f = jax.jit(lambda v: v * 7.0 - 2.0)
+    n0, s0 = COMPILES.n, COMPILES.s
+    f(x).block_until_ready()
+    assert COMPILES.n == n0 + 1 and COMPILES.s > s0
+    f(x).block_until_ready()                          # cached: no compile
+    n1, s1 = COMPILES.n, COMPILES.s
+    assert n1 == n0 + 1
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.5)
+    jax.monitoring.record_event_duration_secs("/other/event", 9.0)
+    assert COMPILES.n == n1 + 1
+    assert COMPILES.s == pytest.approx(s1 + 0.5)
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +364,7 @@ def test_telemetry_write(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Serve metrics: percentile / histogram totality
+# Serve metrics: percentile totality
 # ---------------------------------------------------------------------------
 def test_percentile_edge_cases():
     assert percentile([], 50) == 0.0
@@ -314,20 +376,6 @@ def test_percentile_edge_cases():
     assert percentile([1.0, 2.0, 3.0], 205) == 3.0
     assert percentile([1.0, float("nan"), 3.0], 100) == 3.0
     assert percentile([5.0] * 7, 95) == 5.0
-
-
-def test_histogram_edge_cases():
-    edges, counts = histogram([])
-    assert edges == [0.0, 1.0] and counts == [0]
-    edges, counts = histogram([float("nan")])
-    assert counts == [0]
-    for vals in ([2.0], [2.0, 2.0, 2.0], [1.0, 2.0, 3.0],
-                 [1.0, float("inf"), 3.0]):
-        edges, counts = histogram(vals, bins=8)
-        n_finite = sum(1 for v in vals if math.isfinite(v))
-        assert len(edges) == 9 and len(counts) == 8
-        assert sum(counts) == n_finite
-        assert edges == sorted(edges)
 
 
 def test_serve_metrics_emit_shared_schema():

@@ -494,6 +494,55 @@ def test_extend_matches_prefill(layout):
 
 
 # ---------------------------------------------------------------------------
+# Engine spans: the order of a step's phases, their args, one device_get
+# ---------------------------------------------------------------------------
+PHASES = ["serve.admit", "serve.prepare", "serve.dispatch", "serve.wait",
+          "serve.emit"]
+
+
+def test_engine_step_spans_in_order_with_one_device_get(layout,
+                                                        monkeypatch):
+    import jax
+    from repro.config import reduced
+    from repro.configs.registry import get
+    from repro.models import transformer
+    from repro.obs import Tracer
+    from repro.serve import Engine, Request
+    from repro.serve.scheduler import pad_bucket
+    cfg = reduced(get("tinyllama-1.1b"))
+    params = transformer.init(cfg, layout, jax.random.key(0))
+    tr = Tracer(annotate=False)
+    eng = Engine(cfg, layout, params, batch_size=2, max_len=64, tracer=tr)
+    prompts = [[1, 2, 3, 4, 5], list(range(3, 14))]
+    for i, p in enumerate(prompts):
+        eng.submit(Request(uid=i, prompt=p, max_new=4))
+    gets = []
+    device_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: gets.append(1) or device_get(x))
+    steps = []
+    for kind in ("prefill", "decode"):
+        n_gets, n_events = len(gets), len(tr.events)
+        eng.step()
+        assert len(gets) - n_gets == 1, kind        # no sync added
+        evs = [e for e in tr.events[n_events:]
+               if e["ev"] == "span" and e["track"] == "engine"]
+        (step,) = [e for e in evs if e["name"] == "serve.step"]
+        inner = sorted((e for e in evs if e is not step),
+                       key=lambda e: e["ts"])
+        assert [e["name"] for e in inner] == PHASES, kind
+        assert all(step["ts"] <= e["ts"] and e["ts"] + e["dur"]
+                   <= step["ts"] + step["dur"] for e in inner)
+        assert {"queue", "slots", "compile_n", "compile_s"} <= \
+            set(step["args"])
+        steps.append({e["name"]: e.get("args", {}) for e in inner})
+    lens = [len(p) for p in prompts]
+    assert steps[0]["serve.prepare"] == {
+        "rows": 2, "tokens": sum(lens), "padded": 2 * pad_bucket(max(lens))}
+    assert steps[1]["serve.prepare"] == {"rows": 2, "live": sum(lens)}
+
+
+# ---------------------------------------------------------------------------
 # Engine fast paths: prefix cache and speculative decoding vs the baseline
 # ---------------------------------------------------------------------------
 def test_engine_prefix_and_speculative_match_baseline(layout):
