@@ -16,7 +16,7 @@ from . import ref
 from . import paged_decode as paged_decode_mod
 from .flash_attention import flash_attention
 from .matmul import matmul
-from .paged_decode import paged_flash_decode
+from .paged_decode import live_blocks, paged_flash_decode
 from .rmsnorm import rmsnorm
 from .ssd_scan import ssd_scan
 
@@ -62,10 +62,13 @@ def pallas_rmsnorm(x, gamma, *, eps=1e-6, zero_centered=False, interpret=None):
 
 def pallas_paged_decode(q, k_pool, v_pool, pos_pool, tables, cur, *,
                         block, window=0, scale=None, interpret=None):
-    """Fused paged flash-decode through the block table (serving hot path)."""
+    """Fused paged flash-decode through the block table (serving hot path):
+    every row is decoded, each walks its table up to its last live block."""
+    live = live_blocks(cur, True, block=block, nb=tables.shape[1])
     return paged_flash_decode(q, k_pool, v_pool, pos_pool, tables, cur,
-                              block=block, window=window, scale=scale,
-                              impl="pallas", interpret=_interpret(interpret))
+                              block=block, live=live, window=window,
+                              scale=scale, impl="pallas",
+                              interpret=_interpret(interpret))
 
 
 def enable_kernels(interpret=None):

@@ -18,6 +18,7 @@ Shapes (one layer, per device):
     pos_pool (phys,) int32      logical position per entry, -1 = invalid
     tables   (B, nb) int32      physical block id per view block
     cur      (B,) int32         current decode position per slot
+    live     (B,) int32         table columns each slot walks (optional)
     -> out   (B, nq, dv)
 
 Masking contract: entry ``e`` of slot ``b`` attends iff
@@ -26,6 +27,18 @@ sliding-window).  The fused decode paths keep the current token OUT of the
 pool during the step (the pool is read-only in the forward) and fold its
 (k, v) into the online softmax afterwards via ``return_residuals``; the
 engine then writes all layers' new entries in one batched scatter.
+
+Live-length bound: the pool places position ``p`` of a slot in table
+column ``(p mod view_len) // block``, so only the first
+``cdiv(cur + 1, block)`` columns can hold a position the slot attends
+(the whole table once a sliding-window ring has wrapped).
+``live_blocks`` gives that count per row (0 for a row the step does not
+decode, and the part that falls in a column slice for a caller that
+shards the table).  The kernel walks ``live[b]`` columns of row ``b``:
+past them the K/V/position index maps repeat the row's last live block,
+so the pipeline issues no copy, and the compute is skipped.  A row with
+``live = 0`` returns ``acc = 0, m = NEG_INF, l = 0``, as a walk over
+nothing but masked blocks does.
 
 MLA fits the same kernel with nkv=1: K = concat(c_kv, k_rope) features,
 V = c_kv, q = concat(absorbed q_latent, q_rope) — see ``models/mla.py``.
@@ -67,14 +80,23 @@ def _on_tpu() -> bool:
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
-def _decode_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, kp_ref, o_ref,
-                   mo_ref, lo_ref, m_ref, l_ref, acc_ref, *, nkv: int,
+def live_blocks(cur, active, *, block: int, nb: int, start=0):
+    """Per row, how many of the table columns ``[start, start + nb)`` hold
+    a position ``0 <= p <= cur``: ``clip(cdiv(cur + 1, block) - start, 0,
+    nb)``, and 0 where ``active`` is false.  Works on numpy and jax arrays
+    alike (the engine counts with it on the host, the models bound the
+    kernel with it in the jitted step)."""
+    return ((cur + block) // block - start).clip(0, nb) * active
+
+
+def _decode_kernel(tbl_ref, cur_ref, live_ref, q_ref, k_ref, v_ref, kp_ref,
+                   o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref, *, nkv: int,
                    dk: int, dv: int, window: int, scale: float,
                    residuals: bool):
     # one grid step = one slot x one table block, every kv head at once:
     # k_ref (block, nkv*dk) / v_ref (block, nkv*dv) hold the heads side by
     # side in the lane dim, q_ref and the accumulators are (nkv, g, .)
-    j = pl.program_id(1)
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -82,26 +104,28 @@ def _decode_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, kp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cur = cur_ref[pl.program_id(0)]
-    kp = kp_ref[0, :]                                   # (block,)
-    valid = (kp >= 0) & (kp <= cur)
-    if window:
-        valid &= (cur - kp) < window
-    for h in range(nkv):
-        q = q_ref[h].astype(jnp.float32) * scale        # (g, dk)
-        k = k_ref[:, h * dk:(h + 1) * dk].astype(jnp.float32)   # (block, dk)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, block)
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit re-mask: a fully-invalid block (the null block) would
-        # give exp(NEG_INF - NEG_INF) = 1 on the first grid step otherwise
-        p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
-        v = v_ref[:, h * dv:(h + 1) * dv].astype(jnp.float32)   # (block, dv)
-        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * alpha + p @ v
-        m_ref[h] = m_new
+    @pl.when(j < live_ref[b])
+    def _walk():
+        cur = cur_ref[b]
+        kp = kp_ref[0, :]                               # (block,)
+        valid = (kp >= 0) & (kp <= cur)
+        if window:
+            valid &= (cur - kp) < window
+        for h in range(nkv):
+            q = q_ref[h].astype(jnp.float32) * scale    # (g, dk)
+            k = k_ref[:, h * dk:(h + 1) * dk].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+            s = jnp.where(valid[None, :], s, NEG_INF)   # (g, block)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # explicit re-mask: a fully-invalid block (the null block)
+            # would give exp(NEG_INF - NEG_INF) = 1 on the first step
+            p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
+            v = v_ref[:, h * dv:(h + 1) * dv].astype(jnp.float32)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + p @ v
+            m_ref[h] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -116,8 +140,19 @@ def _decode_kernel(tbl_ref, cur_ref, q_ref, k_ref, v_ref, kp_ref, o_ref,
                           / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
-                 scale, interpret, residuals=False):
+def _pool_block(b, j, tbl, cp, lv):
+    # block-table indirection in the index map: grid step (b, j) pulls
+    # physical block tbl[b, j] out of the pool.  Past the row's live
+    # columns the index repeats the last live one, so no copy is issued.
+    return (tbl[b, jnp.maximum(jnp.minimum(j, lv[b] - 1), 0)], 0, 0)
+
+
+def _row(b, j, tbl, cp, lv):
+    return (b, 0, 0, 0)
+
+
+def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, live, *, block,
+                 window, scale, interpret, residuals=False):
     B, nq, dk = q.shape
     phys, nkv, _ = k_pool.shape
     dv = v_pool.shape[-1]
@@ -137,27 +172,18 @@ def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
                                window=window, scale=scale,
                                residuals=residuals)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((None, nkv, g, dk),
-                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
-            # block-table indirection happens in the index map: grid step
-            # (b, j) pulls physical block tbl[b, j] out of the pool
-            pl.BlockSpec((None, block, nkv * dk),
-                         lambda b, j, tbl, cp: (tbl[b, j], 0, 0)),
-            pl.BlockSpec((None, block, nkv * dv),
-                         lambda b, j, tbl, cp: (tbl[b, j], 0, 0)),
-            pl.BlockSpec((None, 1, block),
-                         lambda b, j, tbl, cp: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((None, nkv, g, dk), _row),
+            pl.BlockSpec((None, block, nkv * dk), _pool_block),
+            pl.BlockSpec((None, block, nkv * dv), _pool_block),
+            pl.BlockSpec((None, 1, block), _pool_block),
         ],
         out_specs=[
-            pl.BlockSpec((None, nkv, g, dv),
-                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
-            pl.BlockSpec((None, nkv, g, 1),
-                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
-            pl.BlockSpec((None, nkv, g, 1),
-                         lambda b, j, tbl, cp: (b, 0, 0, 0)),
+            pl.BlockSpec((None, nkv, g, dv), _row),
+            pl.BlockSpec((None, nkv, g, 1), _row),
+            pl.BlockSpec((None, nkv, g, 1), _row),
         ],
         scratch_shapes=[
             pltpu.VMEM((nkv, g, 1), jnp.float32),
@@ -178,7 +204,8 @@ def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode",
-    )(tables.astype(jnp.int32), cur.astype(jnp.int32), qr, kr, vr, pr)
+    )(tables.astype(jnp.int32), cur.astype(jnp.int32),
+      live.astype(jnp.int32), qr, kr, vr, pr)
     if residuals:
         return (out.reshape(B, nq, dv), m.reshape(B, nq), l.reshape(B, nq))
     return out.reshape(B, nq, dv)
@@ -188,8 +215,8 @@ def _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
 # jnp fallback (CPU serving default): indexes the pool through the tables
 # per layer — no Pallas, but still no all-layer gather_view copy.
 # ---------------------------------------------------------------------------
-def _jnp_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
-              scale, residuals=False):
+def _jnp_impl(q, k_pool, v_pool, pos_pool, tables, cur, live, *, block,
+              window, scale, residuals=False):
     B, nq, dk = q.shape
     nkv = k_pool.shape[1]
     g = nq // nkv
@@ -199,7 +226,10 @@ def _jnp_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
     k = k_pool[flat]                                    # (B, L, nkv, dk)
     v = v_pool[flat]                                    # (B, L, nkv, dv)
     kp = pos_pool[flat]                                 # (B, L)
-    valid = (kp >= 0) & (kp <= cur[:, None])
+    # the same walk as the kernel: columns past live[b] never enter
+    walked = jnp.arange(flat.shape[1]) // block < live[:, None]
+    v = jnp.where(walked[:, :, None, None], v, 0)
+    valid = walked & (kp >= 0) & (kp <= cur[:, None])
     if window:
         valid &= (cur[:, None] - kp) < window
     qf = q.reshape(B, nkv, g, dk).astype(jnp.float32) * scale
@@ -217,12 +247,15 @@ def _jnp_impl(q, k_pool, v_pool, pos_pool, tables, cur, *, block, window,
 
 
 def paged_flash_decode(q, k_pool, v_pool, pos_pool, tables, cur, *,
-                       block: int, window: int = 0,
+                       block: int, live=None, window: int = 0,
                        scale: Optional[float] = None,
                        impl: Optional[str] = None,
                        interpret: Optional[bool] = None,
                        return_residuals: bool = False):
     """One decode step of paged attention; see the module docstring.
+
+    ``live`` (B,) int32 bounds the walk: row ``b`` visits table columns
+    ``0 .. live[b] - 1`` (``live_blocks``); ``None`` walks every column.
 
     ``return_residuals=True`` returns ``(acc, m, l)`` — the unnormalized
     f32 accumulator plus the online-softmax max and sum — so a caller that
@@ -231,6 +264,8 @@ def paged_flash_decode(q, k_pool, v_pool, pos_pool, tables, cur, *,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if live is None:
+        live = jnp.full(cur.shape, tables.shape[1], jnp.int32)
     if impl is None:
         if _FORCED is not None:
             impl, forced_interp = _FORCED
@@ -239,13 +274,13 @@ def paged_flash_decode(q, k_pool, v_pool, pos_pool, tables, cur, *,
         else:
             impl = "pallas" if _on_tpu() else "jnp"
     if impl == "jnp":
-        return _jnp_impl(q, k_pool, v_pool, pos_pool, tables, cur,
+        return _jnp_impl(q, k_pool, v_pool, pos_pool, tables, cur, live,
                          block=block, window=window, scale=scale,
                          residuals=return_residuals)
     if impl != "pallas":
         raise ValueError(f"unknown paged decode impl {impl!r}")
     if interpret is None:
         interpret = not _on_tpu()
-    return _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur,
+    return _pallas_impl(q, k_pool, v_pool, pos_pool, tables, cur, live,
                         block=block, window=window, scale=scale,
                         interpret=interpret, residuals=return_residuals)

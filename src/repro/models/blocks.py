@@ -276,7 +276,7 @@ def attention_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs,
     slice {"k": (phys, nkv, d), "v": ..., "pos": (phys,)}; pos: (B,) int32.
     Returns (out, {"k": (B, nkv, d), "v": (B, nkv, d), "pos": (B,)}).
     """
-    from ..kernels.paged_decode import paged_flash_decode
+    from ..kernels.paged_decode import live_blocks, paged_flash_decode
 
     # the stacked pool leaves carry ONE sharding (built from the canonical
     # entry orientation), so the island pins itself to that orientation
@@ -306,7 +306,7 @@ def attention_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs,
                             (0, nshards - tbl.shape[1] % nshards)))
     nb_loc = tbl.shape[1] // nshards
 
-    def body(q, kn, vn, ck, cv, cpos, tables, pos):
+    def body(q, kn, vn, ck, cv, cpos, tables, pos, active):
         if not kv_sharded and hx > 1:
             hidx = lax.axis_index(head_ax) if head_ax else 0
             kv0 = (hidx * nloc) // group
@@ -315,16 +315,16 @@ def attention_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs,
             cv = lax.dynamic_slice_in_dim(cv, kv0, nkv_loc, axis=1)
             kn = lax.dynamic_slice_in_dim(kn, kv0, nkv_loc, axis=2)
             vn = lax.dynamic_slice_in_dim(vn, kv0, nkv_loc, axis=2)
-        if nshards == 1:
-            tloc = tables
-        else:
-            shard = 0
-            for a in gax:
-                shard = shard * layout.size(a) + lax.axis_index(a)
-            tloc = lax.dynamic_slice_in_dim(tables, shard * nb_loc, nb_loc,
-                                            axis=1)
+        shard = 0
+        for a in gax:
+            shard = shard * layout.size(a) + lax.axis_index(a)
+        tloc = (tables if nshards == 1 else
+                lax.dynamic_slice_in_dim(tables, shard * nb_loc, nb_loc,
+                                         axis=1))
+        live = live_blocks(pos, active, block=blk, nb=nb_loc,
+                           start=shard * nb_loc)
         acc, m, l = paged_flash_decode(q[:, 0], ck, cv, cpos, tloc, pos,
-                                       block=blk, window=window,
+                                       block=blk, live=live, window=window,
                                        return_residuals=True)
         if nshards > 1:
             mg = lax.pmax(m, gax)
@@ -352,9 +352,11 @@ def attention_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs,
     out = shard_map(body, mesh=layout.mesh,
                     in_specs=(qspec, nspec, nspec, kspec, kspec, pspec,
                               P(layout.batch_spec(), None),
+                              P(layout.batch_spec()),
                               P(layout.batch_spec())),
                     out_specs=qspec, check_vma=False)(
-        q, k_new, v_new, cache["k"], cache["v"], cache["pos"], tbl, pos)
+        q, k_new, v_new, cache["k"], cache["v"], cache["pos"], tbl, pos,
+        page.active)
     return out, {"k": k_new[:, 0], "v": v_new[:, 0], "pos": pos}
 
 

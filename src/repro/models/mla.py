@@ -264,7 +264,7 @@ def _mla_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs, q_nope,
     "pos": (phys,)}; pos: (B,) int32.
     Returns (out, {"c_kv": (B, R), "k_rope": (B, dr), "pos": (B,)}).
     """
-    from ..kernels.paged_decode import paged_flash_decode
+    from ..kernels.paged_decode import live_blocks, paged_flash_decode
 
     m, nh, dn, dr, dv = _m(cfg)
     seq_ax, head_ax = _head_axes(layout, dirs)
@@ -290,7 +290,7 @@ def _mla_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs, q_nope,
     else:
         w_spec = P(None, "z")
 
-    def body(qn, qr, cn, krn, cc, ckr, cpos, tables, pos, w_ukv):
+    def body(qn, qr, cn, krn, cc, ckr, cpos, tables, pos, active, w_ukv):
         if layout.strategy == "3d" and layout.size("x") > 1 \
                 and not layout.inference_opt:
             w_ukv = lax.all_gather(w_ukv, "x", axis=1, tiled=True)
@@ -301,17 +301,17 @@ def _mla_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs, q_nope,
         q_cat = jnp.concatenate([qc, qr[:, 0].astype(F32)], axis=-1)
         k_pool = jnp.concatenate([cc, ckr], axis=-1)[:, None, :]
         v_pool = cc[:, None, :]
-        if nshards == 1:
-            tloc = tables
-        else:
-            shard = 0
-            for a in gax:
-                shard = shard * layout.size(a) + lax.axis_index(a)
-            tloc = lax.dynamic_slice_in_dim(tables, shard * nb_loc, nb_loc,
-                                            axis=1)
+        shard = 0
+        for a in gax:
+            shard = shard * layout.size(a) + lax.axis_index(a)
+        tloc = (tables if nshards == 1 else
+                lax.dynamic_slice_in_dim(tables, shard * nb_loc, nb_loc,
+                                         axis=1))
+        live = live_blocks(pos, active, block=blk, nb=nb_loc,
+                           start=shard * nb_loc)
         acc, mx, ls = paged_flash_decode(q_cat, k_pool, v_pool, cpos,
-                                         tloc, pos, block=blk, scale=scale,
-                                         return_residuals=True)
+                                         tloc, pos, block=blk, live=live,
+                                         scale=scale, return_residuals=True)
         if nshards > 1:
             mg = lax.pmax(mx, gax)
             w = jnp.exp(mx - mg)
@@ -332,8 +332,8 @@ def _mla_decode_paged(layout: Layout, cfg: ModelConfig, dirs: Dirs, q_nope,
     out = shard_map(
         body, mesh=layout.mesh,
         in_specs=(qspec, qspec, nspec, nspec, lat_pool, lat_pool, P(None),
-                  P(bs, None), P(bs), w_spec),
+                  P(bs, None), P(bs), P(bs), w_spec),
         out_specs=qspec, check_vma=False)(
         q_nope, q_rope, ckv_new, kr_new, cache["c_kv"], cache["k_rope"],
-        cache["pos"], tbl, pos, w_ukv)
+        cache["pos"], tbl, pos, page.active, w_ukv)
     return out, {"c_kv": ckv_new[:, 0], "k_rope": kr_new[:, 0], "pos": pos}

@@ -38,6 +38,7 @@ import numpy as np
 from ..config import ModelConfig
 from ..core.params import init_params
 from ..core.topology import Layout
+from ..kernels.paged_decode import live_blocks
 from ..models import blocks as B
 from ..models import registry, transformer
 from ..obs.trace import COMPILES, PROFILE
@@ -450,8 +451,17 @@ class Engine:
                 and (req._fed < len(req.prompt) or req.out)]
         if not rows:
             return
+        walk = {}
+        if self.fused:
+            # the paged-decode kernel's walk: table blocks it visits (each
+            # row up to its last live block) of the B x nb it spans
+            nb = self.kv.blocks_per_slot
+            walk = dict(walked=int(live_blocks(self.pos[rows], True,
+                                               block=self.kv.block,
+                                               nb=nb).sum()),
+                        blocks=self.B * nb)
         with tr.span("serve.prepare", track="engine", rows=len(rows),
-                     live=int(self.pos[rows].sum())):
+                     live=int(self.pos[rows].sum()), **walk):
             tok = np.zeros((self.B, 1), np.int32)
             active = np.zeros((self.B,), bool)
             for i in rows:

@@ -1,10 +1,11 @@
 """Compile rehearsals for one TPU v5e chip, made without the chip.
 
 The TPU compiler is installed with JAX and compiles for a topology that is
-described, not attached.  These tests compile the serving decode kernel at
-the published head layouts of the main-path models, so what the chip's
-compiler would refuse (block shapes, fast-memory use) fails here at no chip
-time.  A compile that passes is not a chip run: nothing executes.
+described, not attached.  These tests compile the serving decode kernel,
+its walk bounded per row, at the published head layouts of the main-path
+models, so what the chip's compiler would refuse (block shapes, fast-memory
+use) fails here at no chip time.  A compile that passes is not a chip run:
+nothing executes.
 
 The topology is described inside a fixture, never while a module is
 imported: one process at a time may load the TPU library, and every pytest
@@ -14,13 +15,15 @@ import functools
 
 import pytest
 
-# (q heads, kv heads, key width, value width, query dtype) per published
-# config; MLA attends its compressed latents as one shared kv head
-# (key = kv_lora_rank + qk_rope_dim, value = kv_lora_rank, f32 queries)
+# (q heads, kv heads, key width, value width, query dtype, sliding window)
+# per published config; MLA attends its compressed latents as one shared
+# kv head (key = kv_lora_rank + qk_rope_dim, value = kv_lora_rank, f32
+# queries)
 CASES = {
-    "qwen3-4b-gqa": (32, 8, 128, 128, "bfloat16"),
-    "gemma-2b-mqa": (8, 1, 256, 256, "bfloat16"),
-    "deepseek-v3-mla": (128, 1, 576, 512, "float32"),
+    "qwen3-4b-gqa": (32, 8, 128, 128, "bfloat16", 0),
+    "gemma-2b-mqa": (8, 1, 256, 256, "bfloat16", 0),
+    "deepseek-v3-mla": (128, 1, 576, 512, "float32", 0),
+    "mixtral-8x7b-swa": (32, 8, 128, 128, "bfloat16", 4096),
 }
 BATCH, BLOCK, TABLE_BLOCKS = 8, 16, 128       # 8 slots x 2048 tokens
 
@@ -62,7 +65,7 @@ def test_paged_decode_compiles_for_v5e(one_chip, no_persistent_cache, case,
     import jax
     import jax.numpy as jnp
     from repro.kernels.paged_decode import paged_flash_decode
-    nq, nkv, dk, dv, qdt = CASES[case]
+    nq, nkv, dk, dv, qdt, window = CASES[case]
     phys = (2 + BATCH * TABLE_BLOCKS) * BLOCK
 
     def sds(shape, dtype):
@@ -74,7 +77,10 @@ def test_paged_decode_compiles_for_v5e(one_chip, no_persistent_cache, case,
             sds((phys,), jnp.int32),
             sds((BATCH, TABLE_BLOCKS), jnp.int32),
             sds((BATCH,), jnp.int32))
-    fn = functools.partial(paged_flash_decode, block=BLOCK, impl="pallas",
-                           interpret=False, return_residuals=residuals)
-    compiled = jax.jit(fn).lower(*args).compile()
+    live = sds((BATCH,), jnp.int32)       # the walk's bound, one per row
+    fn = functools.partial(paged_flash_decode, block=BLOCK, window=window,
+                           impl="pallas", interpret=False,
+                           return_residuals=residuals)
+    compiled = jax.jit(
+        lambda *a: fn(*a[:-1], live=a[-1])).lower(*args, live).compile()
     assert "tpu_custom_call" in compiled.as_text()

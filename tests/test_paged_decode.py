@@ -133,6 +133,175 @@ def test_paged_kernel_residual_combine(impl, nshards):
     assert err < 1e-5, err
 
 
+def test_live_blocks_bounds_each_row():
+    """live_blocks: cdiv(cur + 1, block) columns, clipped to the table (a
+    wrapped ring walks it all), 0 for a row the step does not decode, and
+    a shard's part of the bound for a column slice; numpy and jax agree."""
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode import live_blocks
+    cur = np.array([0, 3, 4, 7, 19, 40, 9], np.int32)
+    active = np.array([1, 1, 1, 1, 1, 1, 0], bool)
+    want = [1, 1, 2, 2, 5, 5, 0]
+    assert live_blocks(cur, active, block=4, nb=5).tolist() == want
+    assert live_blocks(jnp.asarray(cur), jnp.asarray(active), block=4,
+                       nb=5).tolist() == want
+    # host counting with every listed row decoded
+    assert live_blocks(cur[:6], True, block=4, nb=5).tolist() == want[:6]
+    # two shards of 3 columns (the table padded to 6): the parts add up
+    parts = [live_blocks(cur, active, block=4, nb=3, start=3 * s)
+             for s in range(2)]
+    assert parts[0].tolist() == [1, 1, 2, 2, 3, 3, 0]
+    assert parts[1].tolist() == [0, 0, 0, 0, 2, 3, 0]
+    assert (parts[0] + parts[1]).tolist() == [1, 1, 2, 2, 5, 6, 0]
+
+
+def _bounded_case(*, cur, active, block, nb, nkv, g, dk, dv, ring=False,
+                  poison=False, seed=0):
+    """Rows that write their positions the way the pool does: position p
+    of row b in table column (p mod view_len) // block, each column its
+    own physical block, columns never written on the null block 0.  A
+    dense row holds 0..cur; a ring row the view's last view_len
+    positions.  An inactive row keeps a stale table full of live blocks.
+    ``poison`` fills the K/V of every block a row's bound leaves out (the
+    null block and the inactive row's blocks) with NaN, positions -1."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode import live_blocks
+    B, L = len(cur), nb * block
+    cur = np.asarray(cur, np.int32)
+    active = np.asarray(active, bool)
+    n_blocks = 2 + B * nb
+    phys = n_blocks * block
+    ks = jax.random.split(jax.random.key(seed), 3)
+    k_pool = np.array(jax.random.normal(ks[0], (phys, nkv, dk)), np.float32)
+    v_pool = np.array(jax.random.normal(ks[1], (phys, nkv, dv)), np.float32)
+    pos_pool = np.full((phys,), -1, np.int32)
+    tables = np.zeros((B, nb), np.int32)
+    nxt = 2
+    for b in range(B):
+        first = max(0, int(cur[b]) - L + 1) if ring else 0
+        for p in range(first, int(cur[b]) + 1):
+            col, off = (p % L) // block, p % block
+            if tables[b, col] == 0:
+                tables[b, col], nxt = nxt, nxt + 1
+            pos_pool[tables[b, col] * block + off] = p
+    live = live_blocks(cur, active, block=block, nb=nb)
+    if poison:
+        dead = {0} | {int(tables[b, j]) for b in range(B)
+                      for j in range(live[b], nb)}
+        for blk_id in dead:
+            rows = slice(blk_id * block, (blk_id + 1) * block)
+            k_pool[rows] = np.nan
+            v_pool[rows] = np.nan
+            pos_pool[rows] = -1
+    q = jax.random.normal(ks[2], (B, nkv * g, dk), jnp.float32)
+    return (q, jnp.asarray(k_pool), jnp.asarray(v_pool),
+            jnp.asarray(pos_pool), jnp.asarray(tables), jnp.asarray(cur),
+            jnp.asarray(active), jnp.asarray(live))
+
+
+# (cur per row, active per row, block, nb, window, ring, table shards)
+BOUNDED = {
+    # live 0 (not decoded, stale table), 1, exactly on a block boundary
+    # (cur + 1 = 2 blocks), a partly filled block, the full table
+    "ragged": ([9, 0, 7, 10, 19], [0, 1, 1, 1, 1], 4, 5, 0, False, 1),
+    # a windowed ring past its wrap (walks all of it) beside one before
+    "ring": ([33, 6, 47], [1, 1, 1], 4, 5, 12, True, 1),
+    # the ragged rows' table cut in 2 and 3 column slices (padded with the
+    # null block), each walked up to its own part of the bound
+    "shard2": ([9, 0, 7, 10, 19], [0, 1, 1, 1, 1], 4, 5, 0, False, 2),
+    "shard3": ([9, 0, 7, 10, 19], [0, 1, 1, 1, 1], 4, 5, 0, False, 3),
+}
+
+
+def _bounded_walk(args, *, impl, block, nb, window, nshards):
+    """The bounded kernel over ``nshards`` column slices, combined by the
+    softmax residuals: (acc, m, l) as one unsharded call gives them."""
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode import live_blocks, paged_flash_decode
+    q, k_pool, v_pool, pos_pool, tables, cur, active, _ = args
+    tbl = jnp.pad(tables, ((0, 0), (0, (-nb) % nshards)))
+    nb_loc = tbl.shape[1] // nshards
+    parts = [paged_flash_decode(
+        q, k_pool, v_pool, pos_pool, tbl[:, s * nb_loc:(s + 1) * nb_loc],
+        cur, block=block, window=window, impl=impl, interpret=True,
+        return_residuals=True,
+        live=live_blocks(cur, active, block=block, nb=nb_loc,
+                         start=s * nb_loc))
+        for s in range(nshards)]
+    if nshards == 1:
+        return parts[0]
+    m = jnp.max(jnp.stack([p[1] for p in parts]), axis=0)
+    acc = sum(p[0] * jnp.exp(p[1] - m)[..., None] for p in parts)
+    l = sum(p[2] * jnp.exp(p[1] - m) for p in parts)
+    return acc, m, l
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("case", list(BOUNDED))
+def test_bounded_walk_matches_oracle(case, impl):
+    """Each row walks only its live table blocks: decoded rows equal the
+    dense oracle and the unbounded walk to float32 round-off; a row the
+    step does not decode returns acc = 0, m = NEG_INF, l = 0 exactly, as a
+    walk over masked blocks alone does; shards combine to the whole."""
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode import NEG_INF, paged_flash_decode
+    cur, active, block, nb, window, ring, nshards = BOUNDED[case]
+    args = _bounded_case(cur=cur, active=active, block=block, nb=nb, nkv=2,
+                         g=4, dk=32, dv=16, ring=ring)
+    q, k_pool, v_pool, pos_pool, tables, cur_a, active_a, live = args
+    acc, m, l = _bounded_walk(args, impl=impl, block=block, nb=nb,
+                              window=window, nshards=nshards)
+    on = np.asarray(active, bool)
+    got = acc / jnp.maximum(l, 1e-30)[..., None]
+    want = _oracle(q, k_pool, v_pool, pos_pool, tables, cur_a, block=block,
+                   window=window)
+    err = float(jnp.max(jnp.abs(got - want)[on]))
+    assert err < 1e-5, err
+    # the unbounded walk: every column, with the idle row's table nulled
+    null_idle = jnp.where(active_a[:, None], tables, 0)
+    full = paged_flash_decode(q, k_pool, v_pool, pos_pool, null_idle, cur_a,
+                              block=block, window=window, impl=impl,
+                              interpret=True, return_residuals=True)
+    for a, b in zip((acc, m, l), full):
+        assert float(jnp.max(jnp.abs(a - b)[on])) <= 1e-5 * float(
+            jnp.max(jnp.abs(b[on])))
+    off = ~on
+    assert off.any() == (case != "ring")
+    if off.any():
+        assert float(jnp.max(jnp.abs(acc[off]))) == 0.0
+        assert float(jnp.max(l[off])) == 0.0
+        assert bool(jnp.all(m[off] == NEG_INF))
+        for a, b in zip((acc, m, l), full):
+            assert bool(jnp.all(a[off] == b[off]))
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_walk_stops_at_the_bound(impl):
+    """K/V of every block past a row's bound hold NaN: the output stays
+    finite and equals the clean oracle, so those blocks are never read
+    into the softmax (an unbounded walk gives NaN through 0 x NaN in
+    p @ v)."""
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode import paged_flash_decode
+    cur, active, block, nb, window, _, _ = BOUNDED["ragged"]
+    kw = dict(cur=cur, active=[1] * len(cur), block=block, nb=nb, nkv=2,
+              g=4, dk=32, dv=16)
+    clean = _bounded_case(**kw)
+    bad = _bounded_case(**kw, poison=True)
+    q, k_pool, v_pool, pos_pool, tables, cur_a, _, live = bad
+    got = paged_flash_decode(q, k_pool, v_pool, pos_pool, tables, cur_a,
+                             block=block, live=live, impl=impl,
+                             interpret=True)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    want = _oracle(*clean[:6], block=block, window=window)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+    unbounded = paged_flash_decode(q, k_pool, v_pool, pos_pool, tables,
+                                   cur_a, block=block, impl=impl,
+                                   interpret=True)
+    assert not bool(jnp.all(jnp.isfinite(unbounded)))
+
+
 def test_scatter_step_batched_writeback():
     """scatter_step lands every layer's new (k, v, pos) entry at its
     physical row in one scatter, trash lanes included."""

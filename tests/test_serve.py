@@ -539,7 +539,11 @@ def test_engine_step_spans_in_order_with_one_device_get(layout,
     lens = [len(p) for p in prompts]
     assert steps[0]["serve.prepare"] == {
         "rows": 2, "tokens": sum(lens), "padded": 2 * pad_bucket(max(lens))}
-    assert steps[1]["serve.prepare"] == {"rows": 2, "live": sum(lens)}
+    # decode: the paged-decode kernel walks each row up to its last live
+    # block (positions 5 and 11 both lie in the first block of 16) of the
+    # 2 rows x 4 blocks its tables span
+    assert steps[1]["serve.prepare"] == {"rows": 2, "live": sum(lens),
+                                         "walked": 2, "blocks": 2 * 4}
 
 
 # ---------------------------------------------------------------------------
