@@ -38,7 +38,9 @@ def main(argv=None):
     ap.add_argument("--block-size", type=int, default=16,
                     help="paged KV cache block size (tokens per block)")
     ap.add_argument("--prefill-chunk", type=int, default=4096,
-                    help="max padded tokens per chunked-prefill step")
+                    help="token budget of a chunked-prefill step: a "
+                         "prompt joins a group if it is at most this / "
+                         "batch size long")
     ap.add_argument("--no-chunked-prefill", action="store_true",
                     help="seed-style sequential prefill (one prompt token "
                          "per engine step) — the throughput baseline")

@@ -14,20 +14,23 @@ Architecture (docs/serving.md has the full picture):
   * ``metrics.ServeMetrics`` — TTFT / TPOT / throughput / queue depth.
 
 Engine steps come in two shapes.  A *prefill* step (paged families) pushes
-a whole padded group of freshly admitted prompts through the jitted
-``transformer.prefill`` — one device call per prompt group instead of one
-per token — scatters the returned kv into the paged pool and emits each
-request's first token.  A *decode* step advances every in-flight slot by
-one token: gather the block-table views, run the decode forward, write the
-new entries back to their blocks, sample on device.  Prefill and decode
-steps interleave: newly admitted work prefills at the next step boundary
-while resident requests keep decoding.  'state' families (no chunked form
-for recurrent state) prefill sequentially through the decode path, exactly
-one prompt token per step, inside the same scheduler/metrics machinery.
+a group of freshly admitted prompts through the jitted
+``transformer.prefill`` — one device call per D prompts (D the layout's
+batch-shard degree, 1 on one chip), each padded to its own power-of-two
+bucket, instead of one call per token — scatters the returned kv into the
+paged pool and emits each request's first token.  A *decode* step advances
+every in-flight slot by one token: gather the block-table views, run the
+decode forward, write the new entries back to their blocks, sample on
+device.  Prefill and decode steps interleave: newly admitted work
+prefills at the next step boundary while resident requests keep decoding.
+'state' families (no chunked form for recurrent state) prefill
+sequentially through the decode path, exactly one prompt token per step,
+inside the same scheduler/metrics machinery.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Callable, List, Optional
 
@@ -135,6 +138,10 @@ class Engine:
                                            n_blocks=n_blocks, dtype=dtype,
                                            prefix_cache=self.prefix)
             self.pool = self.kv.init_pool()
+            # rows of one prefill program: the layout's batch-shard degree
+            # (1 on one chip), the fewest rows its batch sharding allows
+            self._prefill_rows = math.prod(layout.size(a)
+                                           for a in layout.batch_axes)
             self._build_paged()
             self.spec = (draft.build(batch_size, max_len, temperature)
                          if draft is not None else None)
@@ -346,8 +353,8 @@ class Engine:
         Spans, all on the ``engine`` track: ``serve.step`` (queue depth,
         occupied slots, and the process's compile count and seconds, at
         entry) holds in order ``serve.admit``, ``serve.prepare`` (the
-        host builds the program's inputs), ``serve.dispatch`` (the jitted
-        call, with any trace or compile), ``serve.wait`` (the one
+        host builds the programs' inputs), ``serve.dispatch`` (the jitted
+        calls, with any trace or compile), ``serve.wait`` (the one
         ``device_get`` of the sampled tokens) and ``serve.emit`` (tokens,
         metrics hooks, finished requests)."""
         tr = self.tracer
@@ -377,40 +384,26 @@ class Engine:
         lens = {s: len(self.slots[s].prompt)
                 - (self.kv.hit_len(s) if self.prefix else 0)
                 for s in self.scheduler.pending_prefill}
-        group, s_pad = self.scheduler.prefill_group(lens)
+        group, _ = self.scheduler.prefill_group(lens)
+        # the group runs as consecutive programs of D rows (the layout's
+        # batch-shard degree), each padded to its own rows' bucket: no
+        # program spends positions on idle slots or on the group's longest
+        # prompt.  Rows of similar length share a program.
+        D = self._prefill_rows
+        order = sorted(group, key=lambda s: lens[s])
+        runs = [order[i:i + D] for i in range(0, len(order), D)]
+        pads = [pad_bucket(max(lens[s] for s in run)) for run in runs]
         with tr.span("serve.prepare", track="engine", rows=len(group),
                      tokens=sum(lens[s] for s in group),
-                     padded=self.B * s_pad):
-            tokens = np.zeros((self.B, s_pad), np.int32)
-            length = np.zeros((self.B,), np.int32)
-            if self.prefix:
-                offset = np.zeros((self.B,), np.int32)
-                for s in group:
-                    p = self.slots[s].prompt
-                    hit = self.kv.hit_len(s)
-                    tokens[s, :len(p) - hit] = p[hit:]
-                    offset[s] = hit
-                    length[s] = len(p) - hit
-                phys_map = self.kv.extend_phys_map(
-                    {s: (int(offset[s]), int(length[s])) for s in group},
-                    s_pad)
-                run = self._extendf
-                args = (jnp.asarray(tokens), jnp.asarray(offset),
-                        jnp.asarray(length), self.kv.tables_device(),
-                        phys_map)
-            else:
-                for s in group:
-                    p = self.slots[s].prompt
-                    tokens[s, :len(p)] = p
-                    length[s] = len(p)
-                phys_map = self.kv.prefill_phys_map(
-                    {s: lens[s] for s in group}, s_pad)
-                run = self._prefill
-                args = (jnp.asarray(tokens), jnp.asarray(length), phys_map)
+                     padded=D * sum(pads), runs=len(runs)):
+            calls = [self._prefill_args(run, s_row)
+                     for run, s_row in zip(runs, pads)]
             if self.spec is not None:
-                # the draft prefills the FULL prompt into its private cache
-                # — its cache has no prefix sharing, and the propose
-                # bursts need the whole context resident
+                # the draft keeps its own contiguous B-row cache (slot rows
+                # in place, no pool to map into), so it still prefills the
+                # FULL prompt of every group slot in one (B, bucket) call —
+                # its cache has no prefix sharing, and the propose bursts
+                # need the whole context resident
                 d_pad = pad_bucket(max(len(self.slots[s].prompt)
                                        for s in group))
                 dtok = np.zeros((self.B, d_pad), np.int32)
@@ -421,12 +414,19 @@ class Engine:
                     dlen[s] = len(p)
                 draft = (jnp.asarray(dtok), jnp.asarray(dlen))
         with tr.span("serve.dispatch", track="engine"):
-            tok, self.pool = run(self.params, self.pool, *args,
-                                 self._split_key())
+            prog = self._extendf if self.prefix else self._prefill
+            toks = []
+            for args in calls:
+                tok, self.pool = prog(self.params, self.pool, *args,
+                                      self._split_key())
+                toks.append(tok)
             if self.spec is not None:
                 self.spec.prefill(*draft)
         with tr.span("serve.wait", track="engine"):
-            tok = np.asarray(jax.device_get(tok))
+            got = np.concatenate([np.asarray(t)
+                                  for t in jax.device_get(toks)])
+            first = {s: int(got[i * D + j]) for i, run in enumerate(runs)
+                     for j, s in enumerate(run)}
         with tr.span("serve.emit", track="engine"):
             for s in group:
                 req = self.slots[s]
@@ -437,11 +437,37 @@ class Engine:
                     # release below — completed requests still seed the
                     # index
                     self.kv.register_prefix(s)
-                req.out.append(int(tok[s]))
+                req.out.append(first[s])
                 self.metrics.token(req.uid)
                 if (len(req.out) >= req.max_new
                         or self.pos[s] >= self.max_len - 1):
                     self._finish(s)
+
+    def _prefill_args(self, run: List[int], s_row: int) -> tuple:
+        """Inputs of one prefill (or prefix-hit extend) program over the
+        slots ``run``, padded to D rows of ``s_row`` tokens; padding rows
+        carry length 0 and write only to the trash block."""
+        D = self._prefill_rows
+        tokens = np.zeros((D, s_row), np.int32)
+        length = np.zeros((D,), np.int32)
+        offset = np.zeros((D,), np.int32)
+        for j, s in enumerate(run):
+            p = self.slots[s].prompt
+            hit = self.kv.hit_len(s) if self.prefix else 0
+            tokens[j, :len(p) - hit] = p[hit:]
+            offset[j] = hit
+            length[j] = len(p) - hit
+        if not self.prefix:
+            phys_map = self.kv.prefill_phys_map(
+                [(s, int(length[j])) for j, s in enumerate(run)], D, s_row)
+            return jnp.asarray(tokens), jnp.asarray(length), phys_map
+        phys_map = self.kv.extend_phys_map(
+            [(s, int(offset[j]), int(length[j])) for j, s in enumerate(run)],
+            D, s_row)
+        tables = np.zeros((D, self.kv.blocks_per_slot), np.int32)
+        tables[:len(run)] = self.kv.tables[run]
+        return (jnp.asarray(tokens), jnp.asarray(offset), jnp.asarray(length),
+                jnp.asarray(tables), phys_map)
 
     def _decode_tick(self):
         tr = self.tracer
@@ -544,7 +570,9 @@ class Engine:
             vtok = np.zeros((self.B, self._spec_pad()), np.int32)
             vtok[:, 0] = t0
             vtok[:, 1:gamma + 1] = drafts
-            phys_map = self.kv.extend_phys_map(ext, self._spec_pad())
+            phys_map = self.kv.extend_phys_map(
+                [(i, *ext.get(i, (0, 0))) for i in range(self.B)],
+                self.B, self._spec_pad())
             a, emit, self.pool = self._verify(
                 self.params, self.pool, jnp.asarray(vtok), drafts, qprobs,
                 jnp.asarray(posv), jnp.asarray(np.where(active, gamma + 1, 0)

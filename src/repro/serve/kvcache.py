@@ -574,39 +574,42 @@ class PagedKVCache:
     def trash_row(self, row: int) -> int:
         return self.block + row % self.block
 
-    def prefill_phys_map(self, rows_len: Dict[int, int], s_pad: int) -> np.ndarray:
-        """(B, s_pad) flat physical targets for a prefill group: slot ``i``
-        with prompt length ``rows_len[i]`` keeps its last ``view_len``
-        positions (sliding-window ring); everything else -> trash."""
-        out = np.empty((self.B, s_pad), np.int64)
-        for i in range(self.B):
-            out[i, :] = self.trash_row(i)
-            n = rows_len.get(i, 0)
+    def prefill_phys_map(self, rows: Sequence[Tuple[int, int]], n_rows: int,
+                         s_pad: int) -> np.ndarray:
+        """(n_rows, s_pad) flat physical targets for one prefill program:
+        map row ``j`` is ``rows[j] = (slot, prompt_len)``, which keeps its
+        last ``view_len`` positions (sliding-window ring); rows past
+        ``len(rows)`` are padding, and every unkept lane goes to trash."""
+        out = np.empty((n_rows, s_pad), np.int64)
+        for j in range(n_rows):
+            out[j, :] = self.trash_row(j)
+        for j, (slot, n) in enumerate(rows):
             for p in range(max(0, n - self.view_len), min(n, s_pad)):
-                out[i, p] = self.phys(i, p)
+                out[j, p] = self.phys(slot, p)
         return out
 
-    def extend_phys_map(self, rows: Dict[int, Tuple[int, int]],
-                        s_pad: int) -> np.ndarray:
-        """(B, s_pad) flat physical targets for an extend group: slot ``i``
-        with ``rows[i] = (offset, tail_len)`` lands its tail tokens at
-        logical positions offset..offset+tail_len-1; padding -> trash.
+    def extend_phys_map(self, rows: Sequence[Tuple[int, int, int]],
+                        n_rows: int, s_pad: int) -> np.ndarray:
+        """(n_rows, s_pad) flat physical targets for one extend program:
+        map row ``j`` is ``rows[j] = (slot, offset, tail_len)``, whose tail
+        tokens land at logical positions offset..offset+tail_len-1; rows
+        past ``len(rows)`` and padding lanes go to trash.
 
         Positions past the view (a speculative verify near ``max_len``
         would wrap the ring onto live blocks) or landing on an unallocated
         (null) table entry also fall to trash: the engine's accepted-count
         clamp guarantees such tokens are never emitted, so their kv is
         droppable."""
-        out = np.empty((self.B, s_pad), np.int64)
-        for i in range(self.B):
-            out[i, :] = self.trash_row(i)
-            off, n = rows.get(i, (0, 0))
+        out = np.empty((n_rows, s_pad), np.int64)
+        for j in range(n_rows):
+            out[j, :] = self.trash_row(j)
+        for j, (slot, off, n) in enumerate(rows):
             for t in range(min(n, s_pad)):
                 p = off + t
                 if p >= self.view_len \
-                        or self.tables[i, p // self.block] == 0:
+                        or self.tables[slot, p // self.block] == 0:
                     continue
-                out[i, t] = self.phys(i, p)
+                out[j, t] = self.phys(slot, p)
         return out
 
     def cow_rows(self, slots: Sequence[int]):

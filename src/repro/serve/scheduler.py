@@ -16,12 +16,14 @@ module decides *which* requests run.  Contracts:
     list) — a request that doesn't fit *now* stays queued and is retried
     when completions free blocks.
   * ``prefill_group`` picks the next chunk of freshly placed slots to
-    prefill under a token budget: the padded prefill batch costs
-    ``batch_size x S_pad`` device tokens per step, so the group's padded
-    length is capped at ``chunk_tokens / batch_size`` (rounded up to a
-    power-of-two bucket to bound jit retraces); the head of the pending
-    list always runs, whatever its length — budget bounds batching, it
-    never starves a long prompt.
+    prefill under a token budget: a group member's prompt is capped at
+    ``chunk_tokens / batch_size`` tokens, so a group of at most
+    ``batch_size`` prompts stays near ``chunk_tokens``.  The engine runs
+    the group as programs of D rows (the layout's batch-shard degree),
+    each padded to its own rows' power-of-two bucket, so a step costs
+    the sum over its programs of ``D x s_row`` device tokens.  The head
+    of the pending list always runs, whatever its length — budget bounds
+    batching, it never starves a long prompt.
 """
 from __future__ import annotations
 

@@ -537,8 +537,11 @@ def test_engine_step_spans_in_order_with_one_device_get(layout,
             set(step["args"])
         steps.append({e["name"]: e.get("args", {}) for e in inner})
     lens = [len(p) for p in prompts]
+    # prefill: one program per row (batch-shard degree 1 on one device),
+    # each padded to its own bucket
     assert steps[0]["serve.prepare"] == {
-        "rows": 2, "tokens": sum(lens), "padded": 2 * pad_bucket(max(lens))}
+        "rows": 2, "tokens": sum(lens), "runs": 2,
+        "padded": sum(pad_bucket(n) for n in lens)}
     # decode: the paged-decode kernel walks each row up to its last live
     # block (positions 5 and 11 both lie in the first block of 16) of the
     # 2 rows x 4 blocks its tables span
